@@ -42,8 +42,8 @@ def get_trained(scene: str, steps: int = 250, image_hw: int = 56):
                         jax.numpy.asarray(cubes_data[1]), cubes_data[2],
                         cubes_data[3], jax.numpy.asarray(cubes_data[4]))
         return BENCH_CFG, params, cubes
-    # occupancy rebuilds read BENCH_CFG.occ_sigma_thresh (thin scenes like
-    # mic need the low cutoff); the dense params cache keeps the older
+    # occupancy rebuilds read BENCH_CFG.occ_sigma_thresh (one cutoff for
+    # every rebuild site); the dense params cache keeps the older
     # table benchmarks (encoding_table, psnr_table2, ...) dict-based
     res = nerf_train.train_nerf(BENCH_CFG, scene, steps=steps, n_views=8,
                                 image_hw=image_hw, log_every=10_000,

@@ -207,9 +207,17 @@ def main():
                     help="exit non-zero unless the fleet gate holds")
     args = ap.parse_args()
 
+    import jax
+
     from repro.configs.rtnerf import NeRFConfig
     from repro.data import rays as rays_lib
     from repro.obs import snapshot_json
+
+    # fleet workers are processes, which cannot share a TPU chip, so this
+    # benchmark runs on the host CPU: its numbers are never chip numbers
+    dev = jax.devices()[0]
+    platform = f"{dev.platform}/{dev.device_kind}"
+    print(f"[fleet] platform {platform}")
 
     shape = TINY if args.tiny else FULL
     cfg = NeRFConfig(**shape)
@@ -256,6 +264,7 @@ def main():
         speedup = fleet["aggregate_fps"] / single["aggregate_fps"]
         report = {
             "mode": "tiny" if args.tiny else "full",
+            "platform": platform,
             "config": shape,
             "scenes": names,
             "one_scene_bytes": one_scene_bytes,

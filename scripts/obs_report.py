@@ -4,8 +4,8 @@
 Input is a `repro.obs/v1` JSON snapshot — a file written by
 `serve --metrics-dump out.json`, or a live scrape:
 
-    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf \
-        --scene lego --metrics-dump /tmp/obs.json
+    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf --demo \
+        --res 64 --scene lego --metrics-dump /tmp/obs.json
     python scripts/obs_report.py /tmp/obs.json
 
     curl -s http://127.0.0.1:9100/metrics.json | \

@@ -45,17 +45,27 @@ def nerf_param_sharding(cfg: NeRFConfig, params, rules: AxisRules):
     return {k: spec_for(k, v) for k, v in params.items()}
 
 
+def ray_batch_size(rules: AxisRules) -> int:
+    """Devices a ray batch is split over (the mesh's batch axes)."""
+    size = 1
+    for a in ("pod", "data"):
+        size *= rules.mesh.shape.get(a, 1)
+    return size
+
+
 def ray_sharding(rules: AxisRules, n_rays: int):
+    """Rays split over the batch axes. A batch that does not divide them is
+    an error: replicating it would make every device render every ray."""
     mesh = rules.mesh
     batch_axes = [a for a in ("pod", "data") if a in mesh.shape]
-    size = 1
-    for a in batch_axes:
-        size *= mesh.shape[a]
-    if size > 1 and n_rays % size == 0:
-        spec = P(tuple(batch_axes) if len(batch_axes) > 1 else batch_axes[0])
-    else:
-        spec = P()
-    return NamedSharding(mesh, spec)
+    size = ray_batch_size(rules)
+    if size == 1:
+        return NamedSharding(mesh, P())
+    if n_rays % size:
+        raise ValueError(f"{n_rays} rays do not divide over the mesh's "
+                         f"{size} batch devices")
+    return NamedSharding(
+        mesh, P(tuple(batch_axes) if len(batch_axes) > 1 else batch_axes[0]))
 
 
 def stream_sharding(rules: AxisRules) -> NamedSharding:
@@ -78,8 +88,7 @@ def place_field(field, rules: AxisRules):
 
 def shard_rays(rules: AxisRules, rays_o, rays_d):
     """Place one micro-batched ray chunk across the mesh's batch axes
-    (falls back to replication when the chunk doesn't divide the mesh —
-    the single-device path)."""
+    (`ray_sharding`: a chunk that does not divide them raises)."""
     sh = ray_sharding(rules, rays_o.shape[0])
     return jax.device_put(rays_o, sh), jax.device_put(rays_d, sh)
 

@@ -8,7 +8,8 @@ the dense row-block with a vectorised prefix-popcount (the ASIC's fixed
 memory-roofline win is the compression ratio; compute stays dense.
 
 The packed-value expansion is a dynamic VMEM gather — supported in interpret
-mode (our validation target) and on Mosaic TPU v4+; the oracle is
+mode (our validation target); the TPU v5e compiler refuses both kernels
+here (docs/kernels.md support matrix). The oracle is
 ref.bitmap_decode_matmul_ref.
 """
 from __future__ import annotations

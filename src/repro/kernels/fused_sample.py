@@ -42,10 +42,10 @@ Layout contract (shared with `core/tensorf.fused_field_inputs` and
     must be masked out downstream (the render paths multiply them by zero).
 
 Interpret mode is the validated CI target (tests/test_kernels.py fused
-parity suite); real Mosaic lowering needs the dynamic-VMEM-gather support
-of TPU v4+, same as the per-op kernels. The pure-jnp twin
-`fused_sigma_app_ref` is the CPU serving path (dispatched by
-kernels/ops.py) and the parity oracle.
+parity suite). The TPU v5e compiler refuses the kernel (Mosaic's gather
+lowering rejects the in-kernel `jnp.take`; tests/test_tpu_compile.py), so
+the pure-jnp twin `fused_sigma_app_ref` is the serving path on every
+backend (dispatched by kernels/ops.py) as well as the parity oracle.
 """
 from __future__ import annotations
 

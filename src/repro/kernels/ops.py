@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels with platform dispatch.
 
-On TPU the compiled kernels run natively; on CPU we validate in interpret
-mode (`force="pallas"`) or fall back to the jnp oracle (`force="ref"`,
-default on CPU — interpret mode is for correctness, not speed).
+A kernel runs by default only where it compiles: on TPU for the kernels
+the v5e compiler lowers (`flash_attention`), nowhere for the ones it
+refuses (every NeRF kernel; docs/kernels.md support matrix). Otherwise
+the jnp twin serves. A kernel forced with `force="pallas"` runs in
+interpret mode off-TPU (for correctness, not speed) and compiled on TPU.
 """
 from __future__ import annotations
 
@@ -20,16 +22,17 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.volume_render import volume_render as _vr_pallas
 
 
-def _mode(force: Optional[str]) -> str:
+def _mode(force: Optional[str], lowers_on_v5e: bool) -> str:
     if force:
         return force
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    tpu = jax.default_backend() == "tpu"
+    return "pallas" if lowers_on_v5e and tpu else "ref"
 
 
 @functools.partial(jax.jit, static_argnames=("cols", "force"))
 def bitmap_matmul(words, rowptr, values, x, *, cols: int,
                   force: Optional[str] = None):
-    m = _mode(force)
+    m = _mode(force, lowers_on_v5e=False)
     if m == "ref":
         return ref.bitmap_decode_matmul_ref(words, rowptr, values, x, cols)
     return _bitmap_pallas(words, rowptr, values, x, cols=cols,
@@ -39,8 +42,7 @@ def bitmap_matmul(words, rowptr, values, x, *, cols: int,
 @functools.partial(jax.jit, static_argnames=("cols", "force"))
 def bitmap_gather(words, rowptr, values, queries, *, cols: int,
                   force: Optional[str] = None):
-    m = _mode(force)
-    if m == "ref":
+    if _mode(force, lowers_on_v5e=False) == "ref":
         return ref.bitmap_gather_ref(words, rowptr, values, queries, cols)
     return _bitmap_gather_pallas(words, rowptr, values, queries, cols=cols,
                                  interpret=(jax.default_backend() != "tpu"))
@@ -48,8 +50,7 @@ def bitmap_gather(words, rowptr, values, queries, *, cols: int,
 
 @functools.partial(jax.jit, static_argnames=("force",))
 def coo_gather(coords, values, queries, *, force: Optional[str] = None):
-    m = _mode(force)
-    if m == "ref":
+    if _mode(force, lowers_on_v5e=False) == "ref":
         return ref.coo_gather_ref(coords, values, queries)
     return _coo_pallas(coords, values, queries,
                        interpret=(jax.default_backend() != "tpu"))
@@ -57,18 +58,17 @@ def coo_gather(coords, values, queries, *, force: Optional[str] = None):
 
 def fused_mode(force: Optional[str] = None) -> str:
     """Dispatch mode for the fused decode-sample-accumulate path: "fused"
-    (Pallas kernel; interpret off-TPU), "fused_ref" (jnp oracle, the CPU
-    serving default), or whatever explicit mode `force` names ("per-op"
-    makes core/tensorf fall back to the per-op gather composition). The
-    per-op force vocabulary maps onto its fused equivalents so callers can
-    use one force string for the whole hybrid eval."""
+    (Pallas kernel; interpret off-TPU), "fused_ref" (jnp twin, the serving
+    default on every backend — the v5e compiler refuses the kernel), or
+    whatever explicit mode `force` names ("per-op" makes core/tensorf fall
+    back to the per-op gather composition). The per-op force vocabulary
+    maps onto its fused equivalents so callers can use one force string
+    for the whole hybrid eval."""
     if force in ("pallas", "fused"):
         return "fused"
-    if force in ("ref", "fused_ref"):
-        return "fused_ref"
     if force:
-        return force
-    return "fused" if jax.default_backend() == "tpu" else "fused_ref"
+        return "fused_ref" if force == "ref" else force
+    return "fused_ref"
 
 
 fused_supported = fused_sample.fused_supported
@@ -99,7 +99,7 @@ def fused_sigma_app(spec, streams, basis, pts, cube_base, cube_id, *,
 @functools.partial(jax.jit, static_argnames=("delta", "term_eps", "force"))
 def volume_render(sigma, rgb, *, delta: float, term_eps: float = 1e-4,
                   force: Optional[str] = None):
-    m = _mode(force)
+    m = _mode(force, lowers_on_v5e=False)
     if m == "ref":
         return ref.volume_render_ref(sigma, rgb, delta, term_eps)
     return _vr_pallas(sigma, rgb, delta=delta, term_eps=term_eps,
@@ -109,7 +109,7 @@ def volume_render(sigma, rgb, *, delta: float, term_eps: float = 1e-4,
 @functools.partial(jax.jit, static_argnames=("causal", "force"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     force: Optional[str] = None):
-    m = _mode(force)
+    m = _mode(force, lowers_on_v5e=True)
     if m == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal)
     return _flash_pallas(q, k, v, causal=causal,

@@ -4,18 +4,29 @@ novel-view rendering (rtnerf).
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
         --reduced --batch 4 --prompt-len 32 --gen 16
     PYTHONPATH=src python -m repro.launch.serve --arch rtnerf \
+        --scene lego --views 2 --prune-sparsity 0.9
+    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf --demo \
         --scene lego --views 2 --res 64 \
         --prune-sparsity 0.9 --ckpt-dir /tmp/lego-ckpt
-    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf \
-        --scene lego --finetune-steps 200 --finetune-every 50
-    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf \
-        --scenes lego,chair,mic --max-resident-mb 2 --finetune-steps 100
-    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf \
-        --scenes lego,chair,mic --fleet-workers 2 --max-resident-mb 2
+    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf --demo \
+        --scene lego --res 64 --finetune-steps 200 --finetune-every 50
+    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf --demo \
+        --scenes lego,chair,mic --res 64 --max-resident-mb 2 \
+        --finetune-steps 100
+    PYTHONPATH=src python -m repro.launch.serve --arch rtnerf --demo \
+        --scenes lego,chair,mic --res 64 --fleet-workers 2 \
+        --max-resident-mb 2
+
+rtnerf serves the published field config (`configs/rtnerf.CONFIG`: grid
+160, 800x800) unless `--demo` picks the small `demo_config()`. The first
+call compiles; JAX's persistent compile cache lives where
+JAX_COMPILATION_CACHE_DIR says, else in `<checkout>/.jax_cache`.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import time
 
 import jax
@@ -27,6 +38,28 @@ from repro.models import transformer as tf
 from repro.models.common import split_pl
 from repro.models.sharding import make_rules
 from repro.launch.mesh import make_host_mesh
+
+
+def enable_compile_cache():
+    """Keep JAX's persistent compile cache at one fixed path in the
+    checkout, unless JAX_COMPILATION_CACHE_DIR names a directory (JAX reads
+    that variable itself). Called by entry points, never on import."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                            "..", "..", ".."))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(root, ".jax_cache"))
+
+
+def nerf_config(args):
+    """The field config rtnerf serves: the published one, or the small
+    demo shapes with --demo; --max-resident-mb sets the store budget."""
+    from repro.configs.base import mib_to_bytes
+    from repro.configs.rtnerf import CONFIG, demo_config
+
+    cfg = demo_config() if args.demo else CONFIG
+    return dataclasses.replace(
+        cfg, max_resident_bytes=mib_to_bytes(args.max_resident_mb))
 
 
 def serve_lm(args):
@@ -100,8 +133,6 @@ def serve_nerf(args):
     import contextlib
     import json
 
-    from repro.configs.base import mib_to_bytes
-    from repro.configs.rtnerf import NeRFConfig
     from repro.data import rays as rays_lib
     from repro.obs import (MetricsRegistry, MetricsServer, StatsReporter,
                            snapshot_json)
@@ -109,10 +140,7 @@ def serve_nerf(args):
 
     scenes = [s for s in args.scenes.split(",") if s] if args.scenes \
         else [args.scene]
-    cfg = NeRFConfig(grid_res=48, occ_res=48, cube_size=4, max_cubes=1024,
-                     r_sigma=8, r_color=16, app_dim=12, mlp_hidden=32,
-                     max_samples_per_ray=128, train_rays=1024,
-                     max_resident_bytes=mib_to_bytes(args.max_resident_mb))
+    cfg = nerf_config(args)
 
     # the registry is created BEFORE the engine (which may train scenes for
     # minutes) so the exposition endpoint answers scrapes from the start;
@@ -135,7 +163,7 @@ def serve_nerf(args):
         cfg, scenes, ckpt_root=args.ckpt_dir,
         train_steps=args.train_steps, n_views=8, image_hw=args.res,
         prune_sparsity=args.prune_sparsity, encode=not args.dense,
-        ray_chunk=args.res * args.res, max_batch_views=args.views,
+        max_batch_views=args.views,
         auto_flush_interval=(0.25 if args.finetune_steps else None),
         registry=registry)
     holder["engine"] = engine
@@ -181,6 +209,7 @@ def serve_nerf(args):
     # capture lines up with the host-side request spans
     prof = (jax.profiler.trace(args.profile_dir) if args.profile_dir
             else contextlib.nullcontext())
+    failed = 0
     with prof:
         for rnd in range(rounds):
             futures = [(name, engine.submit(cam, gt, scene=name,
@@ -190,6 +219,7 @@ def serve_nerf(args):
             for i, (name, fut) in enumerate(futures):
                 r = fut.result()
                 if r.timed_out:
+                    failed += 1
                     print(f"{name} view {i}: TIMED OUT after "
                           f"{r.latency_s:.2f}s")
                     continue
@@ -209,8 +239,10 @@ def serve_nerf(args):
               f"{total_swaps} live swaps "
               f"(max swap {engine.stats()['swap_latency_s_max'] * 1e3:.1f}ms)")
     s = engine.stats()
-    print(f"served {s['views_served']} views over {s['n_scenes']} scenes, "
-          f"{s['fps']:.3f} FPS (CPU), "
+    devs = jax.devices()
+    print(f"served {s['views_served']} views over {s['n_scenes']} scenes "
+          f"on {devs[0].platform}/{devs[0].device_kind} x{len(devs)}, "
+          f"{s['fps']:.3f} FPS, "
           f"p50={s['latency_p50_s']:.2f}s p95={s['latency_p95_s']:.2f}s, "
           f"ordering-cache hits={s['ordering_cache']['hits']}, "
           f"timeouts={s['timeouts']}, swaps={s['field_swaps']}, "
@@ -235,6 +267,8 @@ def serve_nerf(args):
         reporter.close()
     if mserver is not None:
         mserver.close()
+    if failed:
+        raise SystemExit(f"{failed} view(s) failed")
 
 
 def serve_fleet(args):
@@ -255,16 +289,15 @@ def serve_fleet(args):
     """
     import contextlib
     import json
-    import os
     import shutil
     import tempfile
 
-    from repro.configs.base import mib_to_bytes
-    from repro.configs.rtnerf import NeRFConfig
     from repro.data import rays as rays_lib
     from repro.obs import MetricsRegistry, MetricsServer, snapshot_json
     from repro.serving import FleetRouter, export_scene, prepare_field
+    from repro.serving.router import require_host_processes
 
+    require_host_processes()
     if args.finetune_steps:
         raise SystemExit(
             "--fleet-workers does not combine with --finetune-steps yet: "
@@ -272,10 +305,7 @@ def serve_fleet(args):
             "train a field no worker serves (ROADMAP: fleet fine-tuning)")
     scenes = [s for s in args.scenes.split(",") if s] if args.scenes \
         else [args.scene]
-    cfg = NeRFConfig(grid_res=48, occ_res=48, cube_size=4, max_cubes=1024,
-                     r_sigma=8, r_color=16, app_dim=12, mlp_hidden=32,
-                     max_samples_per_ray=128, train_rays=1024,
-                     max_resident_bytes=mib_to_bytes(args.max_resident_mb))
+    cfg = nerf_config(args)
 
     registry = MetricsRegistry()
     holder = {"router": None}
@@ -310,8 +340,7 @@ def serve_fleet(args):
 
     router = FleetRouter(
         cfg, paths, n_workers=args.fleet_workers,
-        engine_kwargs=dict(ray_chunk=args.res * args.res,
-                           max_batch_views=args.views),
+        engine_kwargs=dict(max_batch_views=args.views),
         registry=registry)
     holder["router"] = router
     try:
@@ -329,6 +358,7 @@ def serve_fleet(args):
                       for cam in cams] for name in scenes}
         prof = (jax.profiler.trace(args.profile_dir) if args.profile_dir
                 else contextlib.nullcontext())
+        failed = 0
         with prof:
             futures = [(name, router.submit(cam, gt, scene=name,
                                             deadline_s=args.deadline))
@@ -337,6 +367,7 @@ def serve_fleet(args):
             for i, (name, fut) in enumerate(futures):
                 r = fut.result()
                 if r.timed_out:
+                    failed += 1
                     print(f"{name} view {i}: TIMED OUT after "
                           f"{r.latency_s:.2f}s")
                     continue
@@ -370,6 +401,8 @@ def serve_fleet(args):
         shutil.rmtree(export_root, ignore_errors=True)
         if mserver is not None:
             mserver.close()
+    if failed:
+        raise SystemExit(f"{failed} view(s) failed")
 
 
 def main():
@@ -403,8 +436,13 @@ def main():
                          "first --scenes entry (the hot scene) on this many "
                          "workers behind one key; the router load-balances "
                          "across the replicas")
+    ap.add_argument("--demo", action="store_true",
+                    help="rtnerf only: serve the small demo_config() field "
+                         "instead of the published CONFIG widths")
     ap.add_argument("--views", type=int, default=2)
-    ap.add_argument("--res", type=int, default=64)
+    ap.add_argument("--res", type=int, default=None,
+                    help="rtnerf only: view height/width in pixels "
+                         "(default: the config's image_hw, 800)")
     ap.add_argument("--train-steps", type=int, default=200)
     ap.add_argument("--dense", action="store_true",
                     help="rtnerf only: serve the raw factor arrays instead "
@@ -454,7 +492,10 @@ def main():
     args = ap.parse_args()
     if args.fleet_workers and args.arch != "rtnerf":
         ap.error("--fleet-workers requires --arch rtnerf")
+    enable_compile_cache()
     if args.arch == "rtnerf":
+        if args.res is None:
+            args.res = nerf_config(args).image_hw
         if args.fleet_workers:
             serve_fleet(args)
         else:

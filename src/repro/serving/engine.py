@@ -21,8 +21,9 @@ paid once per engine (or once per scene):
                     with the same encoded structure — never retrace,
   * ordering      — per-view `order_cubes` schedules are cached per scene
                     by octant ranking (`pipeline.OrderingCache`),
-  * placement     — encoded streams replicated, ray chunks sharded
-                    (`core.distributed`), single-device fallback included,
+  * placement     — encoded streams replicated, ray chunks sharded over
+                    the mesh's batch devices (`core.distributed`; the
+                    chunk must divide them),
   * pair budget   — the active-pair compaction budget adapts to observed
                     occupancy (`aux["active_pairs_max"]`) with hysteresis
                     instead of sitting at the static config default.
@@ -293,6 +294,12 @@ class RenderEngine:
             mesh = make_host_mesh()
         self.rules = make_rules(mesh)
         self.n_devices = int(np.prod(list(mesh.shape.values())))
+        n_batch = distributed.ray_batch_size(self.rules)
+        if self.ray_chunk % n_batch:
+            raise ValueError(
+                f"ray_chunk={self.ray_chunk} does not divide over the "
+                f"mesh's {n_batch} batch devices; pick a multiple of "
+                f"{n_batch}")
 
         if store is not None:
             if field is not None or cubes is not None:

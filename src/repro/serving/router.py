@@ -228,6 +228,21 @@ class _WorkerState:
 # -- router ----------------------------------------------------------------
 
 
+def require_host_processes():
+    """Refuse to run the fleet on a TPU backend. Each fleet worker is its
+    own process with its own RenderEngine, and a TPU chip belongs to one
+    process at a time: the launcher (or the first worker) would hold it and
+    every other worker would fail or hang. In-process replicas, one engine
+    per chip, are the TPU path (ROADMAP, Reach)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "the fleet tier spawns one process per worker, but a TPU chip "
+            "serves one process at a time; run the fleet on CPU "
+            "(JAX_PLATFORMS=cpu) or serve in-process on the chip")
+
+
 class FleetRouter:
     """Scene-affinity router over `n_workers` fleet worker processes.
 
@@ -243,6 +258,7 @@ class FleetRouter:
                  vnodes: int = 64, deadline_s: Optional[float] = None):
         import multiprocessing as mp
 
+        require_host_processes()
         self.cfg = cfg
         self.scene_paths = dict(scenes)
         self.registry = registry if registry is not None else MetricsRegistry()

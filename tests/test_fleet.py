@@ -100,6 +100,20 @@ def test_ring_join_moves_about_one_over_k(k):
     assert len(moved) / len(keys) <= 2.5 / (k + 1)
 
 
+def test_router_refuses_tpu_backend(monkeypatch):
+    """Fleet workers are processes and a TPU chip serves one process at a
+    time: on a tpu backend the router refuses before spawning anyone."""
+    import multiprocessing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spawned = []
+    monkeypatch.setattr(multiprocessing, "get_context",
+                        lambda *a, **k: spawned.append(a))
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        FleetRouter(CFG, {}, n_workers=2)
+    assert not spawned
+
+
 # -- multi-process integration ---------------------------------------------
 
 

@@ -179,3 +179,27 @@ def test_gt_renderer_and_cameras():
     o, d = rendering.camera_rays(cam)
     np.testing.assert_allclose(np.linalg.norm(np.asarray(d), axis=-1), 1.0,
                                rtol=1e-5)
+
+
+def test_scenes_are_the_same_in_every_process():
+    """Procedural scenes seed from a digest of their name, not from the
+    per-process salted str hash: a checkpoint trained in one process
+    pairs with ground truth rendered in another."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    code = ("import sys; sys.path.insert(0, %r)\n"
+            "from repro.data import rays\n"
+            "print(rays.make_scene('ficus').center.tobytes().hex())" % src)
+    outs = set()
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu")
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr
+        outs.add(r.stdout.strip())
+    here = rays_lib.make_scene("ficus").center.tobytes().hex()
+    assert outs == {here}

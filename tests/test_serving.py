@@ -384,8 +384,8 @@ def test_prepare_field_restores_legacy_params_checkpoint(tmp_path):
 
 def test_stream_sharding_multidevice():
     """8 virtual devices: encoded streams replicate, ray chunks shard over
-    the data axis (with replication fallback on non-divisible chunks), and
-    the engine renders correctly on the mesh."""
+    the data axis (a non-divisible chunk is refused, never replicated),
+    and the engine renders correctly on the mesh."""
     import os
     import subprocess
     import sys
@@ -415,7 +415,8 @@ def test_stream_sharding_multidevice():
     occ = occ_lib.build_occupancy(field, cfg, sigma_thresh=0.01)
     cubes = occ_lib.extract_cubes(occ, cfg)
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = jax.make_mesh((8, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = make_rules(mesh)
     cf = distributed.place_field(field.encode(), rules)
     for leaf in jax.tree.leaves(cf):
@@ -423,9 +424,17 @@ def test_stream_sharding_multidevice():
     ro, rd = distributed.shard_rays(rules, jnp.zeros((256, 3)),
                                     jnp.zeros((256, 3)))
     assert not ro.sharding.is_fully_replicated        # 256 % 8 == 0: sharded
-    ro2, _ = distributed.shard_rays(rules, jnp.zeros((100, 3)),
-                                    jnp.zeros((100, 3)))
-    assert ro2.sharding.is_fully_replicated           # fallback: replicated
+    try:                                  # 100 % 8 != 0: no silent
+        distributed.shard_rays(rules, jnp.zeros((100, 3)),  # replication
+                               jnp.zeros((100, 3)))
+        raise AssertionError("non-divisible chunk was placed")
+    except ValueError:
+        pass
+    try:
+        RenderEngine(cfg, cf, cubes, ray_chunk=100, mesh=mesh)
+        raise AssertionError("engine accepted a non-divisible ray_chunk")
+    except ValueError:
+        pass
 
     eng = RenderEngine(cfg, cf, cubes, ray_chunk=256, mesh=mesh)
     r = eng.submit(rays_lib.make_cameras(3, 16, 16)[0]).result()

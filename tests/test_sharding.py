@@ -111,7 +111,8 @@ def test_multidevice_train_step_matches_single():
 
     loss1, _ = jax.jit(lambda p, b: tf.model_loss(p, cfg, b))(params, batch)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = make_rules(mesh)
     p_sh = param_sharding(params, logical, rules)
     _, b_sh = batch_sharding(cfg, shape, rules)
@@ -133,7 +134,8 @@ def test_gpipe_matches_reference():
     from jax.sharding import PartitionSpec as P
     from repro.launch.pipeline import gpipe, mlp_stage, reference_apply
 
-    mesh = jax.make_mesh((4, 2), ("stage", "data"))
+    mesh = jax.make_mesh((4, 2), ("stage", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     L, D, F = 8, 16, 32
     key = jax.random.PRNGKey(0)
     params = {
