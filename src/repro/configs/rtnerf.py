@@ -28,12 +28,13 @@ class NeRFConfig:
     max_cubes: int = 8192            # static bound on non-zero cubes
     step_size: float = 0.5           # march step in voxel units
     max_samples_per_ray: int = 512   # static bound (uniform baseline N)
-    occ_sigma_thresh: float = 1.0    # sigma cutoff for occupancy rebuilds
-                                     # after pruning / before serving. It
-                                     # must exceed softplus(0) = ln 2: a
-                                     # pruned-to-zero cell has that density,
-                                     # and a lower cutoff counts every such
-                                     # cell occupied (fog, saturated cubes)
+    occ_sigma_thresh: float = 0.75   # sigma cutoff for occupancy rebuilds
+                                     # after pruning / before serving: just
+                                     # above softplus(0) = ln 2 = 0.693, the
+                                     # density of a pruned-to-zero cell. At
+                                     # or below ln 2 every such cell counts
+                                     # occupied (fog, saturated cubes);
+                                     # far above it thin parts (mic) drop
     term_eps: float = 1e-4           # early-ray-termination threshold on T
     near: float = 2.0
     far: float = 6.0

@@ -26,8 +26,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.rtnerf import NERF_SHAPES, NeRFConfig, NeRFShape
 from repro.core import rendering, tensorf
+from repro.core.train import field_optimizer
 from repro.models.sharding import AxisRules, make_rules
-from repro.optim import adamw
 
 
 def nerf_param_sharding(cfg: NeRFConfig, params, rules: AxisRules):
@@ -148,9 +148,13 @@ def lower_nerf_cell(cfg: NeRFConfig, shape: NeRFShape, mesh):
             "n_active": cfg.param_count()}
 
     if shape.kind == "train":
-        opt = adamw(lr=cfg.lr_grid)
+        opt = field_optimizer(cfg)
         state_sds = jax.eval_shape(opt.init, params_sds)
-        s_sh = {"step": repl, "m": p_sh, "v": p_sh}
+        # each Adam group's moments shard like the params they track
+        s_sh = {g: {"step": repl,
+                    "m": {k: p_sh[k] for k in st["m"]},
+                    "v": {k: p_sh[k] for k in st["v"]}}
+                for g, st in state_sds.items()}
         fn = build_nerf_train_step(cfg, opt)
         jfn = jax.jit(fn,
                       in_shardings=(p_sh, s_sh,

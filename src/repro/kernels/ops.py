@@ -1,10 +1,10 @@
 """jit'd public wrappers for the Pallas kernels with platform dispatch.
 
-A kernel runs by default only where it compiles: on TPU for the kernels
-the v5e compiler lowers (`flash_attention`), nowhere for the ones it
-refuses (every NeRF kernel; docs/kernels.md support matrix). Otherwise
-the jnp twin serves. A kernel forced with `force="pallas"` runs in
-interpret mode off-TPU (for correctness, not speed) and compiled on TPU.
+The NeRF kernels run only when forced: the v5e compiler refuses them
+(docs/kernels.md support matrix), so their jnp twins serve by default on
+every backend. `flash_attention` runs its kernel by default on TPU. A
+kernel forced with `force="pallas"` runs in interpret mode off-TPU (for
+correctness, not speed) and compiled on TPU.
 """
 from __future__ import annotations
 
@@ -22,18 +22,16 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.volume_render import volume_render as _vr_pallas
 
 
-def _mode(force: Optional[str], lowers_on_v5e: bool) -> str:
+def _mode(force: Optional[str]) -> str:
     if force:
         return force
-    tpu = jax.default_backend() == "tpu"
-    return "pallas" if lowers_on_v5e and tpu else "ref"
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 @functools.partial(jax.jit, static_argnames=("cols", "force"))
 def bitmap_matmul(words, rowptr, values, x, *, cols: int,
                   force: Optional[str] = None):
-    m = _mode(force, lowers_on_v5e=False)
-    if m == "ref":
+    if (force or "ref") == "ref":
         return ref.bitmap_decode_matmul_ref(words, rowptr, values, x, cols)
     return _bitmap_pallas(words, rowptr, values, x, cols=cols,
                           interpret=(jax.default_backend() != "tpu"))
@@ -42,7 +40,7 @@ def bitmap_matmul(words, rowptr, values, x, *, cols: int,
 @functools.partial(jax.jit, static_argnames=("cols", "force"))
 def bitmap_gather(words, rowptr, values, queries, *, cols: int,
                   force: Optional[str] = None):
-    if _mode(force, lowers_on_v5e=False) == "ref":
+    if (force or "ref") == "ref":
         return ref.bitmap_gather_ref(words, rowptr, values, queries, cols)
     return _bitmap_gather_pallas(words, rowptr, values, queries, cols=cols,
                                  interpret=(jax.default_backend() != "tpu"))
@@ -50,7 +48,7 @@ def bitmap_gather(words, rowptr, values, queries, *, cols: int,
 
 @functools.partial(jax.jit, static_argnames=("force",))
 def coo_gather(coords, values, queries, *, force: Optional[str] = None):
-    if _mode(force, lowers_on_v5e=False) == "ref":
+    if (force or "ref") == "ref":
         return ref.coo_gather_ref(coords, values, queries)
     return _coo_pallas(coords, values, queries,
                        interpret=(jax.default_backend() != "tpu"))
@@ -99,8 +97,7 @@ def fused_sigma_app(spec, streams, basis, pts, cube_base, cube_id, *,
 @functools.partial(jax.jit, static_argnames=("delta", "term_eps", "force"))
 def volume_render(sigma, rgb, *, delta: float, term_eps: float = 1e-4,
                   force: Optional[str] = None):
-    m = _mode(force, lowers_on_v5e=False)
-    if m == "ref":
+    if (force or "ref") == "ref":
         return ref.volume_render_ref(sigma, rgb, delta, term_eps)
     return _vr_pallas(sigma, rgb, delta=delta, term_eps=term_eps,
                       interpret=(jax.default_backend() != "tpu"))
@@ -109,7 +106,7 @@ def volume_render(sigma, rgb, *, delta: float, term_eps: float = 1e-4,
 @functools.partial(jax.jit, static_argnames=("causal", "force"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     force: Optional[str] = None):
-    m = _mode(force, lowers_on_v5e=True)
+    m = _mode(force)
     if m == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal)
     return _flash_pallas(q, k, v, causal=causal,

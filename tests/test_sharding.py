@@ -167,3 +167,25 @@ def test_elastic_remesh_8_to_4_devices():
     print("remesh ok")
     """)
     assert "remesh ok" in out
+
+
+def test_nerf_train_cell_lowers_with_trainer_optimizer():
+    """The dry-run train cell steps the field with the trainer's optimizer
+    (split grid/MLP Adam), so its lowering sizes the step that trains."""
+    import jax.numpy as jnp
+
+    from repro.configs.rtnerf import NeRFShape, demo_config
+    from repro.core import tensorf
+    from repro.core.distributed import lower_nerf_cell
+    from repro.core.train import field_optimizer
+
+    cfg = demo_config(tiny=True)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    lowered, _ = lower_nerf_cell(cfg, NeRFShape("t", 256, "train"), mesh)
+    params = jax.eval_shape(lambda k: tensorf.init_field(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    want = jax.eval_shape(field_optimizer(cfg).init, params)
+    (_, state, _), _ = lowered.args_info
+    assert jax.tree.structure(state) == jax.tree.structure(want)
+    lowered.compile()

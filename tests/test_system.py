@@ -14,8 +14,8 @@ def trained_scene():
     cfg = NeRFConfig(grid_res=32, occ_res=32, cube_size=4, max_cubes=512,
                      r_sigma=4, r_color=8, app_dim=8, mlp_hidden=16,
                      max_samples_per_ray=96, train_rays=512)
-    # the occupancy rebuild reads cfg.occ_sigma_thresh (1.0, above the
-    # ln 2 density of a pruned cell); no per-call-site threshold anymore
+    # the occupancy rebuild reads cfg.occ_sigma_thresh (0.75, just above
+    # the ln 2 density of a pruned cell); no per-call-site threshold
     res = nerf_train.train_nerf(cfg, "materials", steps=150, n_views=6,
                                 image_hw=48, log_every=1000, verbose=False)
     return cfg, res
