@@ -29,7 +29,8 @@ import sys
 # without importing repro — this script runs against a snapshot file
 # alone); warp/mask/composite only appear on temporal-tier delta frames
 STAGES = ("warp", "mask", "submit", "queue", "group", "ordering",
-          "compaction", "render", "composite", "deliver")
+          "compaction", "render", "render.dispatch", "render.device",
+          "render.fetch", "composite", "deliver")
 
 _LABELLED = re.compile(r"^(?P<name>[^{]+)\{(?P<labels>.*)\}$")
 
@@ -59,13 +60,13 @@ def stage_table(hists) -> str:
     if not by_stage:
         return "  (no request_stage_s histograms — tracing off or no " \
                "requests served)"
-    hdr = (f"  {'stage':>10s} {'count':>6s} {'p50_ms':>9s} {'p95_ms':>9s} "
+    hdr = (f"  {'stage':>15s} {'count':>6s} {'p50_ms':>9s} {'p95_ms':>9s} "
            f"{'p99_ms':>9s} {'total_s':>8s}")
     rows.append(hdr)
     rows.append("  " + "-" * (len(hdr) - 2))
     for st in known + extra:
         s = by_stage[st]
-        rows.append(f"  {st:>10s} {s['count']:>6d} "
+        rows.append(f"  {st:>15s} {s['count']:>6d} "
                     f"{s['p50'] * 1e3:>9.2f} {s['p95'] * 1e3:>9.2f} "
                     f"{s['p99'] * 1e3:>9.2f} {s['sum']:>8.3f}")
     return "\n".join(rows)

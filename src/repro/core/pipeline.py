@@ -385,9 +385,18 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
     signal the serving engine's adaptive pair-budget loop reads to size the
     budget to the scene instead of the static default.
 
+    Every scan step evaluates the whole budget, hits or not, so `aux` also
+    counts the work done against the work that carried a hit (int32
+    scalars, counted on the device): `scan_steps`, `pair_slots` (steps x
+    budget) and `sample_slots` (pair_slots x samples a segment) are what
+    the step evaluates; `live_steps` the steps whose cube chunk holds a
+    valid cube, `hit_pairs` the evaluated pairs that hit (min(hits,
+    budget) summed over steps), `processed_samples` their in-segment
+    samples.
+
     The field is an argument, not a closure: trace once, serve many, swap
-    freely. `aux` carries per-ray transmittance plus processed/dropped
-    counters.
+    freely. `aux` carries per-ray transmittance, depth and opacity besides
+    the counters.
     """
     delta = step_world(cfg)
     ns = samples_per_segment(cfg)
@@ -408,11 +417,13 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
         n_pairs = chunk * n_rays
         budget = min(pair_budget or max(n_pairs // 4, 128), n_pairs)
 
-        # named_scope markers (zero runtime cost) tag the HLO so XLA
-        # profiler captures (serve --profile-dir) line up with the host-side
-        # span stages in repro/obs/tracing.py (see docs/observability.md)
+        # named_scope markers (zero runtime cost) tag the HLO, so a
+        # profile (serve --profile-dir) names each device op's phase; the
+        # engine's stages reach the same profile as host annotations
+        # (repro/obs/tracing.py, docs/observability.md)
         def body(carry, xs):
-            log_t, color, depth, processed, dropped, pairs_max = carry
+            (log_t, color, depth, processed, dropped, pairs_max, live,
+             hit_pairs) = carry
             ctr, vld = xs                                 # (chunk,3),(chunk,)
 
             # Step 2-1-d: line-slab intersection of every ray with each cube
@@ -479,20 +490,24 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
                 depth = depth.at[ray_i].add(
                     jnp.where(sel, t_here * seg_d, 0.0))
                 log_t = log_t.at[ray_i].add(-seg_tau)
-                processed = processed + jnp.sum(s_mask.astype(jnp.float32))
+                processed = processed + jnp.sum(s_mask.astype(jnp.int32))
                 n_hit = jnp.sum(flat_hit.astype(jnp.int32))
                 dropped = dropped + jnp.maximum(n_hit - budget, 0)
                 pairs_max = jnp.maximum(pairs_max, n_hit)
-            return (log_t, color, depth, processed, dropped, pairs_max), None
+                live = live + jnp.any(vld).astype(jnp.int32)
+                hit_pairs = hit_pairs + jnp.minimum(n_hit, budget)
+            return (log_t, color, depth, processed, dropped, pairs_max,
+                    live, hit_pairs), None
 
         xs = (centers.reshape(n_chunks, chunk, 3),
               valid.reshape(n_chunks, chunk))
+        zero = jnp.int32(0)
         init = (jnp.zeros((n_rays,), jnp.float32),
                 jnp.zeros((n_rays, 3), jnp.float32),
-                jnp.zeros((n_rays,), jnp.float32), jnp.float32(0),
-                jnp.int32(0), jnp.int32(0))
-        (log_t, color, depth, processed, dropped, pairs_max), _ = \
-            jax.lax.scan(body, init, xs)
+                jnp.zeros((n_rays,), jnp.float32), zero, zero, zero, zero,
+                zero)
+        (log_t, color, depth, processed, dropped, pairs_max, live,
+         hit_pairs), _ = jax.lax.scan(body, init, xs)
         t_final = jnp.exp(log_t)
         if white_bg:
             color = color + t_final[:, None]
@@ -504,7 +519,11 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
                        "opacity": 1.0 - t_final,
                        "processed_samples": processed,
                        "dropped_pairs": dropped,
-                       "active_pairs_max": pairs_max}
+                       "active_pairs_max": pairs_max,
+                       "live_steps": live, "hit_pairs": hit_pairs,
+                       "scan_steps": jnp.int32(n_chunks),
+                       "pair_slots": jnp.int32(n_chunks * budget),
+                       "sample_slots": jnp.int32(n_chunks * budget * ns)}
 
     return render
 

@@ -204,9 +204,9 @@ def serve_nerf(args):
     gts = {name: [rays_lib.render_gt(gt_scenes[name], cam) for cam in cams]
            for name in scenes}
     rounds = 1 if not loops else max(args.finetune_rounds, 1)
-    # --profile-dir captures an XLA device profile of the serving rounds;
-    # the jax.named_scope markers in core/pipeline.py tag the HLO so the
-    # capture lines up with the host-side request spans
+    # --profile-dir captures a profile of the serving rounds: device ops
+    # named by core/pipeline.py's scopes, and the engine's stages as
+    # engine.* host annotations on the same clock (repro/obs/tracing.py)
     prof = (jax.profiler.trace(args.profile_dir) if args.profile_dir
             else contextlib.nullcontext())
     failed = 0
@@ -254,7 +254,7 @@ def serve_nerf(args):
     if br:
         print("stage breakdown (per request):")
         for stage, d in br.items():
-            print(f"  {stage:>10s}  n={d['count']:4d} "
+            print(f"  {stage:>15s}  n={d['count']:4d} "
                   f"p50={d['p50_s'] * 1e3:8.2f}ms "
                   f"p99={d['p99_s'] * 1e3:8.2f}ms "
                   f"total={d['total_s']:7.3f}s")
@@ -482,7 +482,8 @@ def main():
                     help="rtnerf only: capture an XLA profiler trace of "
                          "the serving rounds into this directory "
                          "(jax.profiler.trace; named scopes from "
-                         "core/pipeline.py tag the pipeline stages)")
+                         "core/pipeline.py tag the pipeline stages, "
+                         "engine.* annotations the engine's)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="rtnerf only: restore trained fields from "
                          "per-scene subdirectories of this root when "

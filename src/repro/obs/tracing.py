@@ -10,39 +10,50 @@ lifecycle:
     ├── queue       submit -> the flush that claimed the request
     ├── group       (scene, ordering-octant) bucketing of the whole batch
     ├── ordering    per-view ordering-cache lookups for the group
-    ├── compaction  micro-batch planning + ray sharding for the group
-    ├── render      the jitted decode/sample/accumulate steps
-    │                 (attrs: dispatch path, chunks, dropped pairs)
+    ├── compaction  micro-batch planning for the group
+    ├── render      the group's ray chunks through the jitted step
+    │   │             (attrs: dispatch path, chunks, pair and sample counts)
+    │   ├── render.dispatch  rays to the device, the jitted call returning
+    │   ├── render.device    the host waiting on the device's outputs
+    │   └── render.fetch     device-to-host copies, chunks scattered to views
     └── deliver     PSNR + result construction -> future resolution
 
-Group-level stages (group/ordering/compaction/render) are measured once
-per flush group and attached to every member request's trace — each
-request's tree answers "where did MY time go", and the shared intervals
-are exactly the time that request spent in those stages.
+The three `render.*` children repeat once per ray chunk and tile the
+`render` span. Group-level stages (group/ordering/compaction/render) are
+measured once per flush group and attached to every member request's
+trace — each request's tree answers "where did MY time go", and the
+shared intervals are exactly the time that request spent in those stages.
+
+Every stage is timed by `Tracer.stage`, the one way this package times
+one: a `time.perf_counter()` interval recorded on the member requests'
+traces, entered as a `jax.profiler.TraceAnnotation` named
+`<scope>.<stage>` (`engine.render.device`, `store.encode`) over the same
+interval. The annotation is a host event of the profiler, so a captured
+profile (`serve --profile-dir`) shows each stage on the host thread that
+ran it, on the clock of the device ops it drove; outside a capture it
+costs a few hundred nanoseconds.
 
 A `Tracer` mints traces, folds every finished trace's stage durations
 into `request_stage_s{stage=...}` histograms in the shared
 `MetricsRegistry` (where benchmarks and `scripts/obs_report.py` read the
-stage breakdown), counts render dispatch paths
-(`render_dispatch_total{path=...}`), and keeps the last `max_traces`
-completed trees for inspection. `enabled=False` short-circuits everything
-— `start()` returns None and all recording sites no-op — which is what
-the serving benchmark's self-overhead gate toggles.
+stage breakdown) and counts render dispatch paths
+(`render_dispatch_total{path=...}`); the finished tree itself rides on
+the request's result. `enabled=False` short-circuits everything —
+`start()` returns None, stages record no span and enter no annotation —
+which is what the serving benchmark's self-overhead gate toggles.
 
 Span timestamps are `time.perf_counter()` values; trees are exported with
 times relative to the root so they are directly comparable across
-requests. These host-side spans line up with device-side XLA profiler
-captures through the `jax.named_scope` annotations in `core/pipeline.py`
-and `kernels/fused_sample.py` (see docs/observability.md for capturing a
-profile via `serve --profile-dir`).
+requests.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import MetricsRegistry
 
@@ -53,8 +64,11 @@ from repro.obs.registry import MetricsRegistry
 # REPORT_STAGES — the full lifecycle order — and skip empty stages.
 STAGES = ("submit", "queue", "group", "ordering", "compaction", "render",
           "deliver")
+# the children that tile a render span, once per ray chunk
+RENDER_STAGES = ("render.dispatch", "render.device", "render.fetch")
 REPORT_STAGES = ("warp", "mask", "submit", "queue", "group", "ordering",
-                 "compaction", "render", "composite", "deliver")
+                 "compaction", "render") + RENDER_STAGES + ("composite",
+                                                            "deliver")
 
 
 @dataclasses.dataclass
@@ -89,10 +103,6 @@ class ViewTrace:
             self._spans.append(sp)
         return sp
 
-    def span(self, name: str, **attrs):
-        """Context manager measuring one stage on the current thread."""
-        return _SpanCtx(self, name, attrs)
-
     def spans(self) -> List[Span]:
         with self._lock:
             return sorted(self._spans, key=lambda s: s.t0)
@@ -118,30 +128,51 @@ class ViewTrace:
         }
 
 
-class _SpanCtx:
-    def __init__(self, trace: ViewTrace, name: str, attrs: Dict):
-        self._trace, self._name, self._attrs = trace, name, attrs
+class Stage:
+    """One timed stage (`Tracer.stage`): `t0`/`t1` are perf_counter
+    seconds. When the block completes, the interval is added as a span
+    named `name`, with `attrs`, to every trace in `traces`; both may grow
+    inside the block. A block that raises records no span."""
 
-    def __enter__(self) -> Dict:
-        self._t0 = time.perf_counter()
-        return self._attrs          # caller may add attrs inside the block
+    def __init__(self, name: str, label: Optional[str],
+                 traces: Iterable[ViewTrace], attrs: Dict):
+        self.name = name
+        self.traces = [t for t in traces if t is not None]
+        self.attrs = attrs
+        self._label = label
+        self._ann = None
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def dur_s(self) -> float:
+        return max(self.t1 - self.t0, 0.0)
+
+    def __enter__(self) -> "Stage":
+        if self._label is not None:
+            self._ann = TraceAnnotation(self._label)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
 
     def __exit__(self, *exc):
-        self._trace.add(self._name, self._t0, time.perf_counter(),
-                        **self._attrs)
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if exc[0] is None:
+            for tr in self.traces:
+                tr.add(self.name, self.t0, self.t1, **self.attrs)
         return False
 
 
 class Tracer:
-    """Mints ViewTraces and folds finished ones into the registry."""
+    """Mints ViewTraces, times stages, and folds finished traces into the
+    registry. `scope` prefixes the stages' profiler annotations."""
 
-    def __init__(self, registry: MetricsRegistry, *, max_traces: int = 256,
-                 enabled: bool = True):
+    def __init__(self, registry: MetricsRegistry, *, enabled: bool = True,
+                 scope: str = "engine"):
         self.registry = registry
         self.enabled = bool(enabled)
-        self._lock = threading.Lock()
-        self._completed: collections.deque = collections.deque(
-            maxlen=int(max_traces))
+        self.scope = scope
 
     def start(self, view_id: int, scene: str,
               t_submit: Optional[float] = None) -> Optional[ViewTrace]:
@@ -151,10 +182,20 @@ class Tracer:
                          time.perf_counter() if t_submit is None
                          else t_submit)
 
+    def stage(self, name: str, traces: Iterable[ViewTrace] = (),
+              **attrs) -> Stage:
+        """Context manager timing one stage on the host clock; while
+        enabled it also records the span on `traces` and annotates the
+        interval as `<scope>.<name>` for the profiler. Disabled, it only
+        reads the clock."""
+        if not self.enabled:
+            return Stage(name, None, (), attrs)
+        return Stage(name, f"{self.scope}.{name}", traces, attrs)
+
     def finish(self, trace: Optional[ViewTrace],
                t_done: Optional[float] = None):
-        """Close the root span, aggregate stage durations into the shared
-        registry, retain the tree."""
+        """Close the root span and aggregate stage durations into the
+        shared registry."""
         if trace is None:
             return
         trace.t_done = time.perf_counter() if t_done is None else t_done
@@ -166,14 +207,3 @@ class Tracer:
             if path is not None:
                 self.registry.counter("render_dispatch_total",
                                       path=path).inc()
-        with self._lock:
-            self._completed.append(trace)
-
-    def completed(self) -> List[ViewTrace]:
-        """Most-recent-last completed traces (bounded window)."""
-        with self._lock:
-            return list(self._completed)
-
-    def last(self) -> Optional[ViewTrace]:
-        with self._lock:
-            return self._completed[-1] if self._completed else None

@@ -162,8 +162,7 @@ GUARDED_BY = {
         "locks": ("_render_lock",),
         "attrs": ("_queue", "_next_id", "_flusher", "_flush_error",
                   "auto_flush_interval", "_pair_budget", "_pair_window",
-                  "_low_occ_streak", "_pair_occupancy_last",
-                  "_budget_resizes", "_render"),
+                  "_low_occ_streak", "_pair_occupancy_last", "_render"),
         "assume_held": ("_note_flush_pairs", "_build_render"),
     },
 }
@@ -177,7 +176,16 @@ LOCK_ATTR_CLASSES = {
     "RenderEngine._m_render_s": "Counter",
     "RenderEngine._m_flushes": "Counter",
     "RenderEngine._m_latency": "Histogram",
+    "RenderEngine._m_budget_resizes": "Counter",
 }
+
+# renderer aux counters -> the registry counters they advance
+RENDER_COUNTERS = (("scan_steps", "engine_scan_steps"),
+                   ("live_steps", "engine_live_steps"),
+                   ("pair_slots", "engine_pair_slots"),
+                   ("hit_pairs", "engine_hit_pairs"),
+                   ("sample_slots", "engine_sample_slots"),
+                   ("processed_samples", "engine_samples_processed"))
 
 
 def prepare_field(cfg: NeRFConfig, scene: str, *, ckpt_dir: Optional[str],
@@ -334,6 +342,8 @@ class RenderEngine:
         self._m_render_s = m.counter("engine_render_s")
         self._m_dropped = m.counter("engine_dropped_pairs")
         self._m_timeouts = m.counter("engine_timeouts")
+        self._m_budget_resizes = m.counter("engine_pair_budget_resizes")
+        self._m_work = {k: m.counter(name) for k, name in RENDER_COUNTERS}
         self._m_latency = m.histogram("engine_latency_s", maxlen=65536)
         self._g_queue = m.gauge("engine_queue_depth")
         self._g_budget = m.gauge("engine_pair_budget")
@@ -363,7 +373,6 @@ class RenderEngine:
             n_pairs)
         self.pair_budget_initial = self._pair_budget
         self._adaptive_budget = bool(adaptive_pair_budget)
-        self._budget_resizes = 0
         self._pair_window = collections.deque(maxlen=8)
         self._low_occ_streak = 0
         self._pair_occupancy_last = 0.0
@@ -621,9 +630,9 @@ class RenderEngine:
         runs on the caller's thread before the lock — that time is part of
         the request's latency); `pre_spans` are (name, t0, t1, attrs)
         stage spans measured by the caller before the trace existed."""
-        with self._lock:
+        with self.tracer.stage("submit") as st, self._lock:
             fut = ViewFuture(self, self._next_id)
-            now = time.perf_counter()
+            now = st.t0
             t0 = now if t_start is None else t_start
             trace = self.tracer.start(self._next_id, key, t_submit=t0)
             deadline = None if deadline_s is None else now + deadline_s
@@ -634,7 +643,7 @@ class RenderEngine:
             if trace is not None:
                 for name, s0, s1, attrs in pre_spans:
                     trace.add(name, s0, s1, **attrs)
-                trace.add("submit", now, time.perf_counter())
+                st.traces.append(trace)
             full = len(self._queue) >= self.max_batch_views
             if full and self._auto_flush_on():
                 self._flush_cv.notify()
@@ -672,22 +681,22 @@ class RenderEngine:
                   and int(prev.cam.w) == int(cam.w))
         if not usable:
             return self._enqueue(cam, gt, key, deadline_s)
-        t_w0 = time.perf_counter()
-        warp = temporal.warp_radiance(prev.img, prev.cam, cam, prev.depth,
-                                      opacity=prev.opacity)
-        t_w1 = time.perf_counter()
-        plan = temporal.plan_delta(warp, bucket=self.delta_ray_bucket)
-        t_m1 = time.perf_counter()
+        with self.tracer.stage("warp") as w:
+            warp = temporal.warp_radiance(prev.img, prev.cam, cam,
+                                          prev.depth, opacity=prev.opacity)
+        with self.tracer.stage("mask") as m:
+            plan = temporal.plan_delta(warp, bucket=self.delta_ray_bucket)
         n_pix = int(cam.h) * int(cam.w)
         if plan.n_real > max_delta_frac * n_pix:
             self._m_delta_fallbacks.inc()
-            return self._enqueue(cam, gt, key, deadline_s, t_start=t_w0)
-        spans = (("warp", t_w0, t_w1, {}),
-                 ("mask", t_w1, t_m1,
+            return self._enqueue(cam, gt, key, deadline_s, t_start=w.t0)
+        # the trace does not exist yet: hand the intervals to _enqueue
+        spans = (("warp", w.t0, w.t1, {}),
+                 ("mask", m.t0, m.t1,
                   {"fresh_rays": plan.n_rays,
                    "warp_fraction": plan.warp_fraction}))
         return self._enqueue(cam, gt, key, deadline_s, delta=plan,
-                             t_start=t_w0, pre_spans=spans)
+                             t_start=w.t0, pre_spans=spans)
 
     def flush(self) -> List[ViewResult]:
         """Render every queued view: group by (scene, ordering octant),
@@ -758,18 +767,15 @@ class RenderEngine:
         if not live:
             return results
 
-        tg = time.perf_counter()
-        # delta requests batch separately from full frames: their ray sets
-        # are sparse index gathers, and mixing them would make the scatter
-        # ambiguous about which rays rebuild a full image
-        groups = group_requests(
-            live, lambda r: (r.scene, snaps[r.scene].ordering.key_for(
-                r.cam.origin), r.delta is not None))
-        tg1 = time.perf_counter()
-        for r in live:
-            if r.trace is not None:
-                r.trace.add("group", tg, tg1, n_groups=len(groups),
-                            batch_views=len(live))
+        with self.tracer.stage("group", [r.trace for r in live],
+                               batch_views=len(live)) as st:
+            # delta requests batch separately from full frames: their ray
+            # sets are sparse index gathers, and mixing them would make
+            # the scatter ambiguous about which rays rebuild a full image
+            groups = group_requests(
+                live, lambda r: (r.scene, snaps[r.scene].ordering.key_for(
+                    r.cam.origin), r.delta is not None))
+            st.attrs["n_groups"] = len(groups)
 
         flush_pairs = [0, 0]    # [max active pairs, successful render calls]
         flush_dropped = [0]
@@ -793,104 +799,115 @@ class RenderEngine:
                       results: List[ViewResult],
                       snaps: Dict[str, SceneSnapshot], render_fn,
                       flush_pairs: List[int], flush_dropped: List[int]):
+        import jax
+
+        stage = self.tracer.stage
         for (scene, _okey, is_delta), reqs_g in groups.items():
             snap = snaps[scene]
             ordering = snap.ordering
-            traces = [r.trace for r in reqs_g if r.trace is not None]
+            # group-level stages are shared intervals: each member request
+            # spent exactly the stage's interval in it
+            traces = [r.trace for r in reqs_g]
 
-            def span_all(name, t0, t1, **attrs):
-                # group-level stages are shared intervals: each member
-                # request spent exactly [t0, t1] in this stage
-                for tr in traces:
-                    tr.add(name, t0, t1, **attrs)
-
-            tg0 = time.perf_counter()
-            for r in reqs_g:                      # one cache access per view
-                centers, valid = ordering.get_ordered(r.cam.origin)
-            t_ord = time.perf_counter()
-            span_all("ordering", tg0, t_ord,
-                     cache_entries=len(ordering._entries))
-            batches = []
-            for r in reqs_g:
-                o, d = rendering.camera_rays(r.cam)
-                o, d = np.asarray(o), np.asarray(d)
-                if r.delta is not None:
-                    # only the low-confidence rays render; the rest of the
-                    # frame arrives pre-warped in r.delta.warp
-                    o, d = o[r.delta.idx], d[r.delta.idx]
-                batches.append((o, d))
-            plan = plan_microbatches(batches, self.ray_chunk)
-            t_plan = time.perf_counter()
-            span_all("compaction", t_ord, t_plan, n_chunks=plan.n_chunks,
-                     rays=plan.total)
-            outs = []
-            geo_outs = []
-            group_dropped = 0
-            group_pairs_max = 0
-            for i in range(plan.n_chunks):
-                ro, rd = distributed.shard_rays(
-                    self.rules, jnp.asarray(plan.rays_o[i]),
-                    jnp.asarray(plan.rays_d[i]))
-                rgb, aux = render_fn(snap.field, centers, valid, ro, rd)
-                outs.append(np.asarray(rgb))
-                geo_outs.append(np.stack([np.asarray(aux["depth"]),
-                                          np.asarray(aux["opacity"])],
-                                         axis=-1))
-                group_dropped += int(aux["dropped_pairs"])
-                group_pairs_max = max(group_pairs_max,
-                                      int(aux["active_pairs_max"]))
-                flush_pairs[1] += 1
+            with stage("ordering", traces) as st:
+                for r in reqs_g:                  # one cache access per view
+                    centers, valid = ordering.get_ordered(r.cam.origin)
+                st.attrs["cache_entries"] = ordering.stats()["entries"]
+            tg0 = st.t0
+            with stage("compaction", traces) as st:
+                batches = []
+                for r in reqs_g:
+                    o, d = rendering.camera_rays(r.cam)
+                    o, d = np.asarray(o), np.asarray(d)
+                    if r.delta is not None:
+                        # only the low-confidence rays render; the rest of
+                        # the frame arrives pre-warped in r.delta.warp
+                        o, d = o[r.delta.idx], d[r.delta.idx]
+                    batches.append((o, d))
+                plan = plan_microbatches(batches, self.ray_chunk)
+                st.attrs.update(n_chunks=plan.n_chunks, rays=plan.total)
+            # render.dispatch / .device / .fetch tile the render span: the
+            # host's share of it is everything but render.device
+            with stage("render", traces,
+                       dispatch_path=snap.field.dispatch_path(),
+                       n_chunks=plan.n_chunks,
+                       path="delta" if is_delta else "full") as st_render:
+                outs, geo_outs = [], []
+                work = dict.fromkeys(self._m_work, 0)
+                group_dropped = group_pairs_max = 0
+                for i in range(plan.n_chunks):
+                    with stage("render.dispatch", traces):
+                        ro, rd = distributed.shard_rays(
+                            self.rules, jnp.asarray(plan.rays_o[i]),
+                            jnp.asarray(plan.rays_d[i]))
+                        rgb, aux = render_fn(snap.field, centers, valid,
+                                             ro, rd)
+                        want = {k: aux[k] for k in (
+                            "depth", "opacity", "dropped_pairs",
+                            "active_pairs_max", *work)}
+                    with stage("render.device", traces):
+                        jax.block_until_ready((rgb, want))
+                    with stage("render.fetch", traces):
+                        rgb, got = jax.device_get((rgb, want))
+                    outs.append(rgb)
+                    geo_outs.append(np.stack([got["depth"], got["opacity"]],
+                                             axis=-1))
+                    group_dropped += int(got["dropped_pairs"])
+                    group_pairs_max = max(group_pairs_max,
+                                          int(got["active_pairs_max"]))
+                    for k in work:
+                        work[k] += int(got[k])
+                    flush_pairs[1] += 1
+                with stage("render.fetch", traces):
+                    imgs = plan.scatter(outs)
+                    geos = plan.scatter(geo_outs)
+                st_render.attrs.update(dropped_pairs=group_dropped,
+                                       active_pairs_max=group_pairs_max,
+                                       **work)
             flush_pairs[0] = max(flush_pairs[0], group_pairs_max)
             flush_dropped[0] += group_dropped
-            imgs = plan.scatter(outs)
-            geos = plan.scatter(geo_outs)
-            t_done = time.perf_counter()
-            # the render span covers the jitted steps AND the host
-            # transfer (np.asarray blocks on the device); dispatch_path
-            # separates fused / fused_ref / per-op / dense time
-            span_all("render", t_plan, t_done,
-                     dispatch_path=snap.field.dispatch_path(),
-                     n_chunks=plan.n_chunks, dropped_pairs=group_dropped,
-                     active_pairs_max=group_pairs_max,
-                     path="delta" if is_delta else "full")
-            group: List[tuple] = []
-            for r, img, geo in zip(reqs_g, imgs, geos):
-                if r.delta is not None:
-                    img, geo, warp_frac = self._composite_delta(r, img, geo)
-                else:
-                    warp_frac = 0.0
-                psnr = None
-                if r.gt is not None:
-                    psnr = float(rendering.psnr(
-                        jnp.clip(jnp.asarray(img), 0, 1), jnp.asarray(r.gt)))
-                lat = time.perf_counter() - r.t_submit
-                group.append((r, ViewResult(
-                    view_id=r.future._view_id, img=img, psnr=psnr,
-                    latency_s=lat, scene=scene,
-                    depth=np.ascontiguousarray(geo[:, 0]),
-                    opacity=np.ascontiguousarray(geo[:, 1]), cam=r.cam,
-                    warp_fraction=warp_frac, stats={
-                        "occ_accesses": float(snap.cubes.count),
-                        "factor_bytes": float(snap.factor_bytes),
-                        "factor_bytes_dense": float(snap.factor_bytes_dense),
-                    })))
-            # commit the whole group's stats (global, then per-scene), THEN
-            # resolve its futures — a render failure in a later group
-            # leaves this group counted and resolved, unrendered groups
-            # uncounted (they requeue)
-            self._m_dropped.inc(group_dropped)
-            for _, res in group:
-                self._m_latency.record(res.latency_s)
-                self._m_views.inc()
-            self.store.note_served(scene,
-                                   [res.latency_s for _, res in group],
-                                   time.perf_counter() - tg0)
+            with stage("deliver") as st:
+                group: List[tuple] = []
+                for r, img, geo in zip(reqs_g, imgs, geos):
+                    if r.delta is not None:
+                        img, geo, warp_frac = self._composite_delta(r, img,
+                                                                    geo)
+                    else:
+                        warp_frac = 0.0
+                    psnr = None
+                    if r.gt is not None:
+                        psnr = float(rendering.psnr(
+                            jnp.clip(jnp.asarray(img), 0, 1),
+                            jnp.asarray(r.gt)))
+                    lat = time.perf_counter() - r.t_submit
+                    group.append((r, ViewResult(
+                        view_id=r.future._view_id, img=img, psnr=psnr,
+                        latency_s=lat, scene=scene,
+                        depth=np.ascontiguousarray(geo[:, 0]),
+                        opacity=np.ascontiguousarray(geo[:, 1]), cam=r.cam,
+                        warp_fraction=warp_frac, stats={
+                            "occ_accesses": float(snap.cubes.count),
+                            "factor_bytes": float(snap.factor_bytes),
+                            "factor_bytes_dense": float(
+                                snap.factor_bytes_dense),
+                        })))
+                # commit the whole group's stats (global, then per-scene),
+                # THEN resolve its futures — a render failure in a later
+                # group leaves this group counted and resolved, unrendered
+                # groups uncounted (they requeue)
+                self._m_dropped.inc(group_dropped)
+                for k, n in work.items():
+                    self._m_work[k].inc(n)
+                for _, res in group:
+                    self._m_latency.record(res.latency_s)
+                    self._m_views.inc()
+                self.store.note_served(scene,
+                                       [res.latency_s for _, res in group],
+                                       time.perf_counter() - tg0)
             for r, res in group:
                 if r.trace is not None:
-                    t_del = time.perf_counter()
-                    r.trace.add("deliver", t_done, t_del,
-                                psnr=res.psnr)
-                    self.tracer.finish(r.trace, t_done=t_del)
+                    r.trace.add("deliver", st.t0, st.t1, psnr=res.psnr)
+                    self.tracer.finish(r.trace, t_done=st.t1)
                     res.trace = r.trace.tree()
                 results.append(res)
                 r.future._set(res)
@@ -903,23 +920,21 @@ class RenderEngine:
         the temporal-tier telemetry, and return (img, geo, warp_fraction)
         shaped exactly like a full render's."""
         plan = r.delta
-        t_c0 = time.perf_counter()
-        warp = plan.warp
-        img = warp.rgb.astype(np.float32)
-        geo = np.stack([warp.depth, warp.opacity],
-                       axis=-1).astype(np.float32)
-        img[plan.idx] = fresh_img
-        geo[plan.idx] = fresh_geo
+        with self.tracer.stage("composite", [r.trace],
+                               fresh_rays=plan.n_rays,
+                               warp_fraction=plan.warp_fraction):
+            warp = plan.warp
+            img = warp.rgb.astype(np.float32)
+            geo = np.stack([warp.depth, warp.opacity],
+                           axis=-1).astype(np.float32)
+            img[plan.idx] = fresh_img
+            geo[plan.idx] = fresh_geo
         n_pix = warp.confidence.size
         self._m_delta_views.inc()
         self._m_delta_rays.inc(plan.n_real)
         self._m_warp_rays.inc(n_pix - plan.n_real)
         self._m_warp_frac.record(plan.warp_fraction)
         self.metrics.counter("render_dispatch_total", path="delta").inc()
-        if r.trace is not None:
-            r.trace.add("composite", t_c0, time.perf_counter(),
-                        fresh_rays=plan.n_rays,
-                        warp_fraction=plan.warp_fraction)
         return img, geo, plan.warp_fraction
 
     # -- adaptive pair budget ----------------------------------------------
@@ -953,9 +968,10 @@ class RenderEngine:
             self._low_occ_streak = 0
         if new is not None and new != budget:
             self._pair_budget = new
-            self._budget_resizes += 1
+            self._m_budget_resizes.inc()
             self._g_budget.set(new)
-            self._build_render()
+            with self.tracer.stage("rebuild"):
+                self._build_render()
 
     def render_views(self, cams, gts=None, *,
                      scene: Optional[str] = None) -> List[ViewResult]:
@@ -991,7 +1007,7 @@ class RenderEngine:
                 "timeouts": int(self._m_timeouts.value),
                 "pair_budget": self._pair_budget,
                 "pair_budget_initial": self.pair_budget_initial,
-                "pair_budget_resizes": self._budget_resizes,
+                "pair_budget_resizes": int(self._m_budget_resizes.value),
                 "pair_occupancy_last": self._pair_occupancy_last,
                 "auto_flush_interval": self.auto_flush_interval,
                 "auto_flush_running": self._auto_flush_on(),

@@ -40,6 +40,7 @@ histogram — per-publish state never grows for the life of the service.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import tempfile
@@ -47,6 +48,7 @@ import threading
 import time
 from typing import Dict, List, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,7 +58,7 @@ from repro.core import distributed, occupancy as occ_lib
 from repro.core import field as field_lib
 from repro.core import pipeline as rt_pipe
 from repro.core.occupancy import CubeSet
-from repro.obs import Counter, Histogram, MetricsRegistry, lockdebug
+from repro.obs import Counter, Histogram, MetricsRegistry, Tracer, lockdebug
 
 CUBES_FILE = "cubes.npz"
 
@@ -188,6 +190,9 @@ class SceneStore:
         self._revivals_total = self.metrics.counter("store_revivals")
         self._swap_latency_last = self.metrics.gauge(
             "store_swap_latency_s_last")
+        # set-up stages, each into store_prepare_s{stage=...} and onto the
+        # profiler as store.<stage>
+        self._tracer = Tracer(self.metrics, scope="store")
 
     @property
     def evictions_total(self) -> int:
@@ -225,17 +230,32 @@ class SceneStore:
         self._clock += 1
         rec.last_used = self._clock
 
+    @contextlib.contextmanager
+    def _stage(self, name: str):
+        """Time one set-up stage into `store_prepare_s{stage=name}`."""
+        st = self._tracer.stage(name)
+        with st:
+            yield
+        self.metrics.histogram("store_prepare_s", stage=name).record(
+            st.dur_s)
+
     def _prepare(self, field, cubes: Optional[CubeSet]):
         """Coerce -> normalise representation -> place on the mesh. encode
         serves the hybrid streams (no-op when pre-encoded); encode=False
         decodes, the dense-baseline toggle. Cubes rebuild at the shared
-        `cfg.occ_sigma_thresh` when not supplied."""
+        `cfg.occ_sigma_thresh` when not supplied. Each stage is timed
+        (`_stage`); placement waits for its transfers, so its span holds
+        them."""
         field = field_lib.as_backend(field, self.cfg)
-        field = field.encode() if self.encode_fields else field.decode()
-        field = distributed.place_field(field, self.rules)
+        with self._stage("encode" if self.encode_fields else "decode"):
+            field = field.encode() if self.encode_fields else field.decode()
+        with self._stage("place"):
+            field = jax.block_until_ready(
+                distributed.place_field(field, self.rules))
         if cubes is None:
-            occ = occ_lib.build_occupancy(field, self.cfg)
-            cubes = occ_lib.extract_cubes(occ, self.cfg)
+            with self._stage("occupancy"):
+                occ = occ_lib.build_occupancy(field, self.cfg)
+                cubes = occ_lib.extract_cubes(occ, self.cfg)
         return field, cubes
 
     # -- scene lifecycle ---------------------------------------------------

@@ -20,7 +20,7 @@ from repro.data import rays as rays_lib
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
                        MetricsServer, StatsReporter, Tracer, snapshot_json,
                        to_prometheus)
-from repro.obs.tracing import STAGES
+from repro.obs.tracing import RENDER_STAGES, STAGES
 from repro.serving import RenderEngine, SceneStore
 
 CFG = NeRFConfig(grid_res=24, occ_res=24, cube_size=4, max_cubes=256,
@@ -199,7 +199,7 @@ def test_tracer_span_tree_and_stage_histograms():
     assert reg.histogram("request_stage_s", stage="render").last == \
         pytest.approx(0.5)
     assert reg.counter("render_dispatch_total", path="fused").value == 1
-    assert tr.last() is t
+    assert t.t_done == 100.71
 
 
 def test_tracer_disabled_noops():
@@ -207,18 +207,63 @@ def test_tracer_disabled_noops():
     tr = Tracer(reg, enabled=False)
     assert tr.start(1, "lego") is None
     tr.finish(None)                       # must not raise
-    assert tr.completed() == []
+    other = Tracer(reg).start(2, "lego", t_submit=0.0)
+    with tr.stage("render", [other]) as st:
+        pass
+    assert st.dur_s >= 0.0                # the clock still reads
+    assert other.spans() == []            # ... but nothing is recorded
     assert reg.metrics() == []
 
 
-def test_tracer_completed_window_bounded():
-    reg = MetricsRegistry()
-    tr = Tracer(reg, max_traces=4)
-    for i in range(10):
-        tr.finish(tr.start(i, "s", t_submit=float(i)))
-    done = tr.completed()
-    assert len(done) == 4
-    assert [t.view_id for t in done] == [6, 7, 8, 9]
+def test_tracer_stage_records_on_traces():
+    """A stage adds one span, with attrs set inside the block, to every
+    trace it holds, including one appended inside; a block that raises
+    records none."""
+    tr = Tracer(MetricsRegistry())
+    a = tr.start(1, "lego", t_submit=0.0)
+    b = tr.start(2, "lego", t_submit=0.0)
+    with tr.stage("render", [a, None], n_chunks=2) as st:
+        st.attrs["dropped_pairs"] = 0
+        st.traces.append(b)
+    assert st.t1 >= st.t0 > 0.0
+    for t in (a, b):
+        (sp,) = t.spans()
+        assert (sp.name, sp.t0, sp.t1) == ("render", st.t0, st.t1)
+        assert sp.attrs == {"n_chunks": 2, "dropped_pairs": 0}
+    with pytest.raises(RuntimeError):
+        with tr.stage("deliver", [a]):
+            raise RuntimeError("render failed")
+    assert [sp.name for sp in a.spans()] == ["render"]
+
+
+class _Annotations:
+    """Stands in for `jax.profiler.TraceAnnotation`, keeping the labels
+    entered, in order."""
+
+    def __init__(self):
+        self.labels = []
+        self.open = 0
+        log = self
+
+        class Ann:
+            def __init__(self, label):
+                self.label = label
+
+            def __enter__(self):
+                log.labels.append(self.label)
+                log.open += 1
+
+            def __exit__(self, *exc):
+                log.open -= 1
+        self.cls = Ann
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from repro.obs import tracing
+    ann = _Annotations()
+    monkeypatch.setattr(tracing, "TraceAnnotation", ann.cls)
+    return ann
 
 
 # -- engine integration ----------------------------------------------------
@@ -244,13 +289,13 @@ def test_request_span_tree_complete():
         engine.default_scene).field.dispatch_path()
     assert render["dur_s"] > 0
     assert res.trace["dur_s"] >= render["dur_s"]
-    # tracer kept the tree; stage histograms carry one observation each
-    assert engine.tracer.last().view_id == res.trace["view_id"]
+    # the result carries its own tree; stage histograms one observation each
+    assert res.trace["view_id"] == res.view_id
     for stage in STAGES:
         assert engine.metrics.histogram("request_stage_s",
                                         stage=stage).count >= 1
     br = engine.stage_breakdown()
-    assert set(br) == set(STAGES)
+    assert set(br) == set(STAGES) | set(RENDER_STAGES)
     assert br["render"]["count"] == 1
 
 
@@ -269,10 +314,11 @@ def test_engine_stats_registry_backed_and_tracing_toggle():
     assert snap["histograms"]["engine_latency_s"]["count"] == 1
     # tracing off: requests render fine, no new traces minted
     engine.set_tracing(False)
-    n_before = len(engine.tracer.completed())
+    renders = engine.metrics.histogram("request_stage_s", stage="render")
+    n_before = renders.count
     r = engine.render_views([cam])[0]
     assert r.trace is None and not r.timed_out and r.img is not None
-    assert len(engine.tracer.completed()) == n_before
+    assert renders.count == n_before
     assert engine.stats()["views_served"] == 2
 
 
@@ -367,3 +413,73 @@ def test_engine_registry_conflict_rejected():
     with pytest.raises(ValueError):
         RenderEngine(CFG, store=store, registry=MetricsRegistry(),
                      ray_chunk=64)
+
+
+def test_engine_stages_annotated_for_the_profiler(annotations):
+    """Traced, every engine stage enters an `engine.<stage>` annotation
+    (the render children once per chunk) and leaves it; untraced, none
+    is entered while the work counters still advance."""
+    field, cubes = _field_and_cubes()
+    engine = RenderEngine(CFG, field, cubes, ray_chunk=64,
+                          max_batch_views=2)
+    cam = rays_lib.make_cameras(1, 12, 12)[0]
+    annotations.labels.clear()
+    res = engine.render_views([cam])[0]
+    seen = set(annotations.labels)
+    for stage in ("submit", "group", "ordering", "compaction", "render",
+                  "deliver") + RENDER_STAGES:
+        assert f"engine.{stage}" in seen, stage
+    n_chunks = next(s for s in res.trace["stages"]
+                    if s["name"] == "render")["n_chunks"]
+    assert annotations.labels.count("engine.render.device") == n_chunks
+    assert annotations.open == 0
+    steps = engine.metrics.counter("engine_scan_steps").value
+    engine.set_tracing(False)
+    annotations.labels.clear()
+    r = engine.render_views([cam])[0]
+    assert r.trace is None and annotations.labels == []
+    assert engine.metrics.counter("engine_scan_steps").value == 2 * steps
+
+
+def test_render_children_tile_render_span():
+    """render.dispatch, render.device and render.fetch lie inside their
+    render span, without overlap, once per chunk (fetch once more for the
+    scatter)."""
+    field, cubes = _field_and_cubes()
+    engine = RenderEngine(CFG, field, cubes, ray_chunk=64,
+                          max_batch_views=2)
+    cams = rays_lib.make_cameras(2, 12, 12)
+    for res in engine.render_views(cams):
+        st = res.trace["stages"]
+        render = next(s for s in st if s["name"] == "render")
+        lo, hi = render["t0_s"], render["t0_s"] + render["dur_s"]
+        kids = sorted((s for s in st if s["name"] in RENDER_STAGES),
+                      key=lambda s: s["t0_s"])
+        counts = {n: sum(s["name"] == n for s in kids) for n in RENDER_STAGES}
+        assert counts == {"render.dispatch": render["n_chunks"],
+                          "render.device": render["n_chunks"],
+                          "render.fetch": render["n_chunks"] + 1}
+        end = lo
+        for s in kids:
+            assert s["t0_s"] >= end - 1e-9
+            end = s["t0_s"] + s["dur_s"]
+        assert end <= hi + 1e-9
+        assert sum(s["dur_s"] for s in kids) <= render["dur_s"] + 1e-9
+
+
+@pytest.mark.parametrize("encode", [True, False])
+def test_store_prepare_stages_timed(annotations, encode):
+    """Registration times its set-up stages into store_prepare_s and
+    annotates them as store.<stage>; occupancy only when it is built."""
+    field, cubes = _field_and_cubes()
+    store = SceneStore(CFG, encode=encode)
+    store.register("given", field, cubes)
+    store.register("built", field)
+    first = "encode" if encode else "decode"
+    h = {st: store.metrics.histogram("store_prepare_s", stage=st)
+         for st in (first, "place", "occupancy")}
+    assert (h[first].count, h["place"].count, h["occupancy"].count) == \
+        (2, 2, 1)
+    assert all(x.sum > 0 for x in h.values())
+    assert annotations.labels == [f"store.{first}", "store.place"] * 2 + \
+        ["store.occupancy"]
