@@ -28,6 +28,7 @@ structural envelope violations still exit on first hit.
     python scripts/check_metrics_schema.py /tmp/obs.json \
         --expect-counter engine_views_served \
         --expect-counter engine_samples_processed \
+        --expect-counter engine_eval_steps \
         --expect-histogram engine_latency_s \
         --expect-histogram store_prepare_s \
         --expect-counter fleet_requests_total \

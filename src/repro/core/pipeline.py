@@ -354,6 +354,15 @@ def compact_select(flat_hit: jax.Array, budget: int) -> jax.Array:
     return jnp.argsort(key)[:budget]
 
 
+def eval_rungs(budget: int) -> Tuple[int, ...]:
+    """The pair-slot counts a render scan step's field evaluation comes in,
+    ascending: budget/16, budget/4 and budget, each at least
+    min(budget, 128), duplicates dropped (8192 -> (512, 2048, 8192))."""
+    floor = min(budget, 128)
+    return tuple(sorted({max(budget // 16, floor), max(budget // 4, floor),
+                         budget}))
+
+
 def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
                       pair_budget: int = None, white_bg: bool = True):
     """Ray-centric RT-NeRF renderer (serving path).
@@ -376,23 +385,32 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
     Sec. 3.1's "process only pre-existing points" is realised by active-pair
     compaction: per scan step the (chunk, N) ray-cube pairs are tested
     geometrically (cheap) and only the hitting pairs — gathered into a
-    static `pair_budget` — go through the field/MLP evaluation (expensive).
-    Typical scenes hit a few % of pairs, so this is the serving path's main
-    algorithmic win over the per-view loop. Pairs beyond the budget are
-    dropped and counted in `aux["dropped_pairs"]` (0 in every measured
-    scene at the default budget of chunk*N // 4); `aux["active_pairs_max"]`
-    is the max hitting-pair count over the scan steps — the occupancy
-    signal the serving engine's adaptive pair-budget loop reads to size the
-    budget to the scene instead of the static default.
+    static number of pair slots — go through the field/MLP evaluation
+    (expensive). Typical scenes hit a few % of pairs, so this is the
+    serving path's main algorithmic win over the per-view loop. Pairs beyond
+    `pair_budget` are dropped and counted in `aux["dropped_pairs"]` (0 in
+    every measured scene at the default budget of chunk*N // 4);
+    `aux["active_pairs_max"]` is the max hitting-pair count over the scan
+    steps — the occupancy signal the serving engine's adaptive pair-budget
+    loop reads to size the budget to the scene instead of the static
+    default.
 
-    Every scan step evaluates the whole budget, hits or not, so `aux` also
-    counts the work done against the work that carried a hit (int32
-    scalars, counted on the device): `scan_steps`, `pair_slots` (steps x
-    budget) and `sample_slots` (pair_slots x samples a segment) are what
-    the step evaluates; `live_steps` the steps whose cube chunk holds a
-    valid cube, `hit_pairs` the evaluated pairs that hit (min(hits,
-    budget) summed over steps), `processed_samples` their in-segment
-    samples.
+    Each step sizes its evaluation to its hits, on the device: a step with
+    no hit evaluates nothing (no compaction, field evaluation or scatter);
+    any other takes the smallest of `eval_rungs(budget)` that holds
+    min(hits, budget). The rung's slots are the first `rung` entries of the
+    same hit-first order (`compact_select`), so the selected pairs, and
+    those dropped past the budget, are the budget's own; padding slots add
+    exact zeros. `aux` counts the work done against the work that carried
+    a hit (int32 scalars, counted on the device): `scan_steps`;
+    `eval_steps` the steps that evaluated; `pair_slots` the pair slots
+    evaluated (the chosen rungs summed) and `sample_slots` those x samples
+    a segment; `live_steps` the steps whose cube chunk holds a valid cube,
+    `hit_pairs` the evaluated pairs that hit (min(hits, budget) summed
+    over steps), `processed_samples` their in-segment samples.
+
+    The renderer must not be vmapped: under vmap the per-step switch
+    becomes a select that evaluates every rung.
 
     The field is an argument, not a closure: trace once, serve many, swap
     freely. `aux` carries per-ray transmittance, depth and opacity besides
@@ -416,14 +434,80 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
         n_chunks = (nc + pad) // chunk
         n_pairs = chunk * n_rays
         budget = min(pair_budget or max(n_pairs // 4, 128), n_pairs)
+        rungs = eval_rungs(budget)
+        rung_arr = jnp.asarray(rungs, jnp.int32)
+        slots_of = jnp.asarray((0,) + rungs, jnp.int32)   # per branch
+
+        def skip(log_t, color, depth, ctr, flat_hit, t0, t1):
+            return log_t, color, depth, jnp.int32(0)
 
         # named_scope markers (zero runtime cost) tag the HLO, so a
         # profile (serve --profile-dir) names each device op's phase; the
         # engine's stages reach the same profile as host annotations
         # (repro/obs/tracing.py, docs/observability.md)
+        def evaluate(rung):
+            """The step's field evaluation in `rung` pair slots."""
+            def run(log_t, color, depth, ctr, flat_hit, t0, t1):
+                # active-pair compaction: hitting pairs first (stable),
+                # cut to the rung, evaluate the field only there
+                with jax.named_scope("rtnerf.compact"):
+                    idx = compact_select(flat_hit, rung)  # hits lead
+                    sel = flat_hit[idx]                   # (rung,)
+                    ray_i = idx % n_rays
+                    t0s = t0.reshape(-1)[idx]
+                    t1s = t1.reshape(-1)[idx]
+                    ro_s = rays_o[ray_i]
+                    rd_s = rays_d[ray_i]
+
+                    ts = t0s[:, None] + (jnp.arange(ns)[None] + 0.5) * delta
+                    s_mask = sel[:, None] & (ts < t1s[:, None])  # (rung,ns)
+                    pts = ro_s[:, None] + rd_s[:, None] * ts[..., None]
+                    flat = pts.reshape(-1, 3)
+                    # points grouped by chunk-local cube (idx // n_rays) so
+                    # encoded fields stream per-cube factor windows through
+                    # the fused kernel; non-selected pairs land out-of-window
+                    # and are masked below
+                    cube_i = (idx // n_rays).astype(jnp.int32)
+                    cid = jnp.broadcast_to(cube_i[:, None],
+                                           s_mask.shape).reshape(-1)
+                with jax.named_scope("rtnerf.field_eval"):
+                    sigma, feats = f.sigma_app(flat, ctr, cid)
+                    sigma = jnp.where(s_mask, sigma.reshape(s_mask.shape),
+                                      0.0)
+                    dirs = jnp.broadcast_to(rd_s[:, None],
+                                            pts.shape).reshape(-1, 3)
+                    rgb = f.color(feats, dirs).reshape(*s_mask.shape, 3)
+
+                # per-pair local compositing along the segment
+                with jax.named_scope("rtnerf.composite"):
+                    tau = sigma * delta
+                    cum = jnp.cumsum(tau, axis=-1)
+                    t_local = jnp.exp(-(cum - tau))
+                    alpha = 1.0 - jnp.exp(-tau)
+                    w = t_local * alpha
+                    seg_rgb = jnp.sum(w[..., None] * rgb, axis=-2)  # (rung,3)
+                    seg_d = jnp.sum(w * ts, axis=-1)                # (rung,)
+                    seg_tau = jnp.where(sel, cum[..., -1], 0.0)     # (rung,)
+
+                # scatter into the per-ray accumulators (pre-chunk T,
+                # exactly the image path's chunk>1 approximation)
+                with jax.named_scope("rtnerf.scatter"):
+                    t_here = jnp.exp(log_t)[ray_i]
+                    contrib = jnp.where(sel[:, None],
+                                        t_here[:, None] * seg_rgb, 0.0)
+                    color = color.at[ray_i].add(contrib)
+                    depth = depth.at[ray_i].add(
+                        jnp.where(sel, t_here * seg_d, 0.0))
+                    log_t = log_t.at[ray_i].add(-seg_tau)
+                    processed = jnp.sum(s_mask.astype(jnp.int32))
+                return log_t, color, depth, processed
+            return run
+
+        branches = [skip] + [evaluate(r) for r in rungs]
+
         def body(carry, xs):
             (log_t, color, depth, processed, dropped, pairs_max, live,
-             hit_pairs) = carry
+             hit_pairs, evaluated, slots) = carry
             ctr, vld = xs                                 # (chunk,3),(chunk,)
 
             # Step 2-1-d: line-slab intersection of every ray with each cube
@@ -438,76 +522,38 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
                 # yield no samples and must not consume pair-budget slots
                 hit = (t1 > t0) & (t1 > cfg.near) & vld[:, None] & alive[None]
                 t0 = jnp.maximum(t0, cfg.near)
-
-            # active-pair compaction: hitting pairs first (stable), cut to
-            # the static budget, evaluate the field only there
-            with jax.named_scope("rtnerf.compact"):
                 flat_hit = hit.reshape(-1)                # (chunk*N,)
-                idx = compact_select(flat_hit, budget)    # hits lead
-                sel = flat_hit[idx]                       # (budget,)
-                ray_i = idx % n_rays
-                t0s = t0.reshape(-1)[idx]
-                t1s = t1.reshape(-1)[idx]
-                ro_s = rays_o[ray_i]
-                rd_s = rays_d[ray_i]
-
-                ts = t0s[:, None] + (jnp.arange(ns)[None] + 0.5) * delta
-                s_mask = sel[:, None] & (ts < t1s[:, None])  # (budget,ns)
-                pts = ro_s[:, None] + rd_s[:, None] * ts[..., None]
-                flat = pts.reshape(-1, 3)
-                # points grouped by chunk-local cube (idx // n_rays) so
-                # encoded fields stream per-cube factor windows through the
-                # fused kernel; non-selected pairs land out-of-window and
-                # are masked below
-                cube_i = (idx // n_rays).astype(jnp.int32)
-                cid = jnp.broadcast_to(cube_i[:, None],
-                                       s_mask.shape).reshape(-1)
-            with jax.named_scope("rtnerf.field_eval"):
-                sigma, feats = f.sigma_app(flat, ctr, cid)
-                sigma = jnp.where(s_mask, sigma.reshape(s_mask.shape), 0.0)
-                dirs = jnp.broadcast_to(rd_s[:, None],
-                                        pts.shape).reshape(-1, 3)
-                rgb = f.color(feats, dirs).reshape(*s_mask.shape, 3)
-
-            # per-pair local compositing along the segment
-            with jax.named_scope("rtnerf.composite"):
-                tau = sigma * delta
-                cum = jnp.cumsum(tau, axis=-1)
-                t_local = jnp.exp(-(cum - tau))
-                alpha = 1.0 - jnp.exp(-tau)
-                w = t_local * alpha
-                seg_rgb = jnp.sum(w[..., None] * rgb, axis=-2)  # (budget,3)
-                seg_d = jnp.sum(w * ts, axis=-1)                # (budget,)
-                seg_tau = jnp.where(sel, cum[..., -1], 0.0)     # (budget,)
-
-            # scatter into the per-ray accumulators (pre-chunk T, exactly
-            # the image path's chunk>1 approximation)
-            with jax.named_scope("rtnerf.scatter"):
-                t_here = jnp.exp(log_t)[ray_i]
-                contrib = jnp.where(sel[:, None],
-                                    t_here[:, None] * seg_rgb, 0.0)
-                color = color.at[ray_i].add(contrib)
-                depth = depth.at[ray_i].add(
-                    jnp.where(sel, t_here * seg_d, 0.0))
-                log_t = log_t.at[ray_i].add(-seg_tau)
-                processed = processed + jnp.sum(s_mask.astype(jnp.int32))
                 n_hit = jnp.sum(flat_hit.astype(jnp.int32))
+
+            # branch 0 skips a step with no hit; branch k evaluates rung k-1,
+            # the smallest that holds min(n_hit, budget)
+            with jax.named_scope("rtnerf.compact"):
+                need = jnp.minimum(n_hit, budget)
+                rung_k = jnp.sum((rung_arr < need).astype(jnp.int32))
+                branch = jnp.where(n_hit > 0, 1 + rung_k, 0)
+                log_t, color, depth, n_proc = jax.lax.switch(
+                    branch, branches, log_t, color, depth, ctr, flat_hit, t0,
+                    t1)
+
+            with jax.named_scope("rtnerf.scatter"):
+                processed = processed + n_proc
                 dropped = dropped + jnp.maximum(n_hit - budget, 0)
                 pairs_max = jnp.maximum(pairs_max, n_hit)
                 live = live + jnp.any(vld).astype(jnp.int32)
-                hit_pairs = hit_pairs + jnp.minimum(n_hit, budget)
+                hit_pairs = hit_pairs + need
+                evaluated = evaluated + (branch > 0).astype(jnp.int32)
+                slots = slots + slots_of[branch]
             return (log_t, color, depth, processed, dropped, pairs_max,
-                    live, hit_pairs), None
+                    live, hit_pairs, evaluated, slots), None
 
         xs = (centers.reshape(n_chunks, chunk, 3),
               valid.reshape(n_chunks, chunk))
         zero = jnp.int32(0)
         init = (jnp.zeros((n_rays,), jnp.float32),
                 jnp.zeros((n_rays, 3), jnp.float32),
-                jnp.zeros((n_rays,), jnp.float32), zero, zero, zero, zero,
-                zero)
+                jnp.zeros((n_rays,), jnp.float32)) + (zero,) * 7
         (log_t, color, depth, processed, dropped, pairs_max, live,
-         hit_pairs), _ = jax.lax.scan(body, init, xs)
+         hit_pairs, evaluated, slots), _ = jax.lax.scan(body, init, xs)
         t_final = jnp.exp(log_t)
         if white_bg:
             color = color + t_final[:, None]
@@ -522,8 +568,9 @@ def make_ray_renderer(cfg: NeRFConfig, *, chunk: int = 8,
                        "active_pairs_max": pairs_max,
                        "live_steps": live, "hit_pairs": hit_pairs,
                        "scan_steps": jnp.int32(n_chunks),
-                       "pair_slots": jnp.int32(n_chunks * budget),
-                       "sample_slots": jnp.int32(n_chunks * budget * ns)}
+                       "eval_steps": evaluated,
+                       "pair_slots": slots,
+                       "sample_slots": slots * ns}
 
     return render
 

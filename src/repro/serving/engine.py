@@ -182,6 +182,7 @@ LOCK_ATTR_CLASSES = {
 # renderer aux counters -> the registry counters they advance
 RENDER_COUNTERS = (("scan_steps", "engine_scan_steps"),
                    ("live_steps", "engine_live_steps"),
+                   ("eval_steps", "engine_eval_steps"),
                    ("pair_slots", "engine_pair_slots"),
                    ("hit_pairs", "engine_hit_pairs"),
                    ("sample_slots", "engine_sample_slots"),

@@ -32,21 +32,24 @@ def scene():
     return field, cubes
 
 
-def recount(centers, valid, ro, rd, budget):
-    """The renderer's counters from the ordered cube arrays and the
-    line-slab hits, in float32 numpy as the renderer computes them."""
+def rungs_of(budget):
+    """The renderer's rung rule restated: budget/16, budget/4, budget, each
+    at least min(budget, 128)."""
+    floor = min(budget, 128)
+    return sorted({max(budget // 16, floor), max(budget // 4, floor),
+                   budget})
+
+
+def step_hits(centers, valid, ro, rd):
+    """Per scan step: (valid cubes, flat hit mask, t0, t1) of the line-slab
+    test, in float32 numpy as the renderer computes them."""
     half = np.float32(CFG.cube_world() / 2.0)
     near = np.float32(CFG.near)
-    delta = np.float32(pipeline.step_world(CFG))
-    ns = pipeline.samples_per_segment(CFG)
-    n_rays = ro.shape[0]
     n_steps = math.ceil(len(valid) / CHUNK)
     pad = n_steps * CHUNK - len(valid)
     centers = np.concatenate([centers, np.zeros((pad, 3), np.float32)])
     valid = np.concatenate([valid, np.zeros(pad, bool)])
     safe_d = np.where(np.abs(rd) < 1e-9, np.float32(1e-9), rd)
-    out = dict.fromkeys(("live_steps", "hit_pairs", "processed_samples",
-                         "dropped_pairs"), 0)
     for s in range(n_steps):
         ctr = centers[s * CHUNK:(s + 1) * CHUNK]
         vld = valid[s * CHUNK:(s + 1) * CHUNK]
@@ -55,17 +58,34 @@ def recount(centers, valid, ro, rd, budget):
         t0 = np.max(np.minimum(ta, tb), axis=-1)
         t1 = np.min(np.maximum(ta, tb), axis=-1)
         hit = ((t1 > t0) & (t1 > near) & vld[:, None]).reshape(-1)
+        yield vld, hit, t0.reshape(-1), t1.reshape(-1)
+
+
+def recount(centers, valid, ro, rd, budget):
+    """The renderer's counters from the ordered cube arrays and the
+    line-slab hits: a step with no hit evaluates nothing, any other the
+    smallest rung that holds min(hits, budget)."""
+    near = np.float32(CFG.near)
+    delta = np.float32(pipeline.step_world(CFG))
+    ns = pipeline.samples_per_segment(CFG)
+    rungs = rungs_of(budget)
+    out = dict.fromkeys(("scan_steps", "live_steps", "eval_steps",
+                         "pair_slots", "hit_pairs", "processed_samples",
+                         "dropped_pairs"), 0)
+    for vld, hit, t0, t1 in step_hits(centers, valid, ro, rd):
         sel = np.flatnonzero(hit)[:budget]     # hits in pair order, cut
-        ts = (np.maximum(t0.reshape(-1)[sel], near)[:, None]
+        ts = (np.maximum(t0[sel], near)[:, None]
               + (np.arange(ns, dtype=np.float32)[None] + np.float32(0.5))
               * delta)
+        out["scan_steps"] += 1
         out["live_steps"] += int(vld.any())
+        if len(sel):
+            out["eval_steps"] += 1
+            out["pair_slots"] += min(r for r in rungs if r >= len(sel))
         out["hit_pairs"] += len(sel)
         out["dropped_pairs"] += int(hit.sum()) - len(sel)
-        out["processed_samples"] += int(
-            (ts < t1.reshape(-1)[sel][:, None]).sum())
-    out.update(scan_steps=n_steps, pair_slots=n_steps * budget,
-               sample_slots=n_steps * budget * ns)
+        out["processed_samples"] += int((ts < t1[sel][:, None]).sum())
+    out["sample_slots"] = out["pair_slots"] * ns
     return out
 
 
@@ -87,6 +107,61 @@ def test_renderer_counters_equal_numpy_recount(scene, budget):
     assert want["live_steps"] == math.ceil(cubes.count / CHUNK)
     assert want["hit_pairs"] > 0
     assert (want["dropped_pairs"] > 0) == (budget == 16)
+
+
+def fan_view(centers, valid, origin, per_step, seed=0):
+    """One eye's rays aimed at points inside the valid cubes of chosen scan
+    steps, `per_step` {step: rays}: a view whose steps hit from none to
+    thousands of pairs."""
+    rng = np.random.RandomState(seed)
+    aims = []
+    for step, n in per_step.items():
+        ctr = centers[step * CHUNK:(step + 1) * CHUNK]
+        ctr = ctr[valid[step * CHUNK:(step + 1) * CHUNK]]
+        jitter = rng.uniform(-0.4, 0.4, (n, 3)) * CFG.cube_world()
+        aims.append(ctr[rng.randint(0, len(ctr), n)] + jitter)
+    d = np.concatenate(aims) - origin
+    d = (d / np.linalg.norm(d, axis=-1, keepdims=True)).astype(np.float32)
+    return np.broadcast_to(origin, d.shape).astype(np.float32), d
+
+
+@pytest.mark.parametrize("budget", [1024, 2048])
+def test_rungs_render_what_the_whole_budget_renders(scene, budget,
+                                                    monkeypatch):
+    """A view with steps of no hit, steps at each rung and (budget 1024)
+    steps past the budget renders, counts and drops what the same renderer
+    does with every step evaluating the whole budget."""
+    field, cubes = scene
+    origin = np.asarray(rays_lib.make_cameras(1, 12, 12)[0].origin)
+    perm = pipeline.order_cubes(cubes, jnp.asarray(origin))
+    centers, valid = cubes.centers[perm], cubes.valid[perm]
+    ro, rd = fan_view(np.asarray(centers), np.asarray(valid), origin,
+                      {3: 20, 9: 150, 15: 600, 21: 1300})
+    steps = [(vld.any(), int(hit.sum())) for vld, hit, _, _ in
+             step_hits(np.asarray(centers), np.asarray(valid), ro, rd)]
+    rungs = rungs_of(budget)
+    hits = [h for _, h in steps]
+    assert len(rungs) == 3
+    assert {min(r for r in rungs if r >= min(h, budget))
+            for h in hits if h} == set(rungs)
+    assert (False, 0) in steps and (True, 0) in steps
+    assert (max(hits) > budget) == (budget == 1024)
+
+    def render():
+        step = jax.jit(pipeline.make_ray_renderer(CFG, chunk=CHUNK,
+                                                  pair_budget=budget))
+        return step(field, centers, valid, jnp.asarray(ro), jnp.asarray(rd))
+
+    rgb, aux = render()
+    monkeypatch.setattr(pipeline, "eval_rungs", lambda b: (b,))
+    rgb_all, aux_all = render()
+    assert int(aux["pair_slots"]) < int(aux_all["pair_slots"])
+    for got, want in ((rgb, rgb_all), (aux["t_final"], aux_all["t_final"]),
+                      (aux["depth"], aux_all["depth"])):
+        assert float(jnp.max(jnp.abs(got - want))) <= 1e-6
+    for key in ("hit_pairs", "dropped_pairs", "active_pairs_max",
+                "processed_samples"):
+        assert int(aux[key]) == int(aux_all[key]), key
 
 
 def _engine(scene, **kw):
@@ -178,16 +253,34 @@ def test_engine_counters_match_for_repeated_view(scene):
     assert deltas[0] == deltas[1]
 
 
-def test_static_slots_follow_budget(scene):
-    """Slot counts are what the step evaluates: steps x budget (x ns)."""
+def test_evaluated_slots_follow_rungs(scene):
+    """A view that hits nothing evaluates no slot in any step; a view that
+    hits evaluates one rung a hitting step (budget 128: the one rung 128),
+    at least its hits, in no more steps than are live."""
     engine = _engine(scene, pair_budget=128)
-    engine.render_views(rays_lib.make_cameras(1, 12, 12))
-    c = {n: engine.metrics.counter(n).value for _, n in RENDER_COUNTERS}
-    assert c["engine_pair_slots"] == c["engine_scan_steps"] * 128
+    cam = rays_lib.make_cameras(1, 12, 12)[0]
+    away = rendering.look_at_camera(cam.origin, 2 * cam.origin, cam.focal,
+                                    cam.h, cam.w)
+
+    def counters_of(view):
+        before = {n: engine.metrics.counter(n).value
+                  for _, n in RENDER_COUNTERS}
+        engine.render_views([view])
+        return {n: engine.metrics.counter(n).value - v
+                for n, v in before.items()}
+
+    c = counters_of(away)
+    assert c["engine_scan_steps"] > 0 and c["engine_live_steps"] > 0
+    assert c["engine_hit_pairs"] == 0
+    assert c["engine_eval_steps"] == c["engine_pair_slots"] == 0
+    assert c["engine_sample_slots"] == 0
+    c = counters_of(cam)
+    assert c["engine_eval_steps"] > 0
+    assert c["engine_pair_slots"] == c["engine_eval_steps"] * 128
     assert c["engine_sample_slots"] == \
         c["engine_pair_slots"] * pipeline.samples_per_segment(CFG)
-    assert c["engine_hit_pairs"] <= c["engine_pair_slots"]
-    assert c["engine_live_steps"] <= c["engine_scan_steps"]
+    assert c["engine_pair_slots"] >= c["engine_hit_pairs"] > 0
+    assert c["engine_eval_steps"] <= c["engine_live_steps"]
 
 
 def test_renderer_counters_are_int32(scene):
