@@ -1,13 +1,13 @@
 """Readings that the comparison's limits are set from, for one cell.
 
 For each seed: the seed's field is published into one engine (set up and
-warmed up once, as a run does), the cell's traffic for that seed is
-served, and each served view is compared with the float32 reference (what
-a sound run reads). On the first `--control-seeds` seeds the control is
-read too, and has to come out not correct: the configuration's `control`
-entry, the program itself at a lower `matmul_precision` (its views are
-served again after every seed's) or the reference in a lower `dtype` put
-in the program's place.
+warmed up once, as a run does), the first views of the window's passes
+for that seed are served, and each served view is compared with the
+float32 reference (what a sound run reads). On the first
+`--control-seeds` seeds the control is read too, and has to come out not
+correct: the configuration's `control` entry, the program itself at a
+lower `matmul_precision` (its views are served again after every seed's)
+or the reference in a lower `dtype` put in the program's place.
 
     python3 bench/control.py --workload <cell> --seeds 101 102 103 \
         [--views 1] [--control-seeds 3] [--rehearse]
@@ -30,49 +30,48 @@ def readings(workload, seeds, views=1, control_seeds=3, rehearse=False,
 
     from bench import compare, device, geometry, harness, inputs, reference
     from bench import traffic
-    from repro.configs.rtnerf import NeRFConfig
-    from repro.core import field as field_lib
     from repro.core import occupancy as occ_lib
 
     log = log or (lambda m: print(f"[control] {m}", file=sys.stderr,
                                   flush=True))
-    _, cell, conf, mix = harness.load_cell(workload)
+    _, cell, conf, mix, arch = harness.load_cell(workload)
     control = control or conf["control"]
     w = dict(conf["field"])
     if rehearse:
-        w.update(harness.rehearsal_widths())
+        w.update(arch.rehearsal_widths())
         conf = dict(conf, field=w)
     harness.enable_compile_cache()
     harness.set_precision(conf)
     devs = device.devices(cell["chips"], rehearse)
-    cfg = NeRFConfig(**w)
     occ = inputs.occupancy(conf)
     centers = inputs.cube_centers(conf, occ)
-    cubes = occ_lib.extract_cubes(jnp.asarray(occ), cfg)
     scene = conf["scene"]["name"]
-    engine = None
+    engine = cubes = None
     out, again = [], []
     for k, seed in enumerate(seeds):
-        params = inputs.make_weights(conf, seed)
-        field = field_lib.DenseField(params, cfg)
+        params = arch.make_weights(conf, seed)
+        cfg, field = arch.build(conf, w, params)
         if engine is None:
+            cubes = occ_lib.extract_cubes(jnp.asarray(occ), cfg)
             engine = harness.make_engine(cfg, field, cubes, conf, scene,
                                          harness.make_mesh(devs))
             harness.warm_up(engine, mix, centers, harness.CompileLog(),
                             log)
         else:
             engine.swap_field(field, cubes)
-        poses = traffic.poses(mix, centers, seed)
+        poses = (p for batch in traffic.passes(mix, centers, seed)
+                 for p in batch)
         served = [harness.serve(engine, next(poses)) for _ in range(views)]
         imgs, refs, ctrl = [], [], []
         for v in served:
             ro, rd = v.pose.rays()
             h = geometry.hits(w, centers, ro, rd, engine.cube_chunk)
             imgs.append(v.img)
-            refs.append(reference.render(params, w, h, ro, rd))
+            refs.append(reference.render(arch.reference_field, params, w, h,
+                                         ro, rd))
             if k < control_seeds and "dtype" in control:
-                ctrl.append(reference.render(params, w, h, ro, rd,
-                                             **control)[0])
+                ctrl.append(reference.render(arch.reference_field, params, w,
+                                             h, ro, rd, **control)[0])
         if k < control_seeds and "matmul_precision" in control:
             again.append((k, field, [v.pose for v in served], refs))
         prog = compare.readings(imgs, refs)

@@ -56,7 +56,27 @@ class View:
     timed_out: bool
 
 
+def load_module(kind: str, name: str):
+    """`bench/<kind>/<name>.py`, loaded by file path and registered in
+    `sys.modules` (as dataclasses and pickling look a module up there)."""
+    path = os.path.join(ROOT, "bench", kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(name: str):
+    """The module of a field architecture (`bench/archs/<name>.py`): its
+    weights, program objects, reference field, work and trace scopes."""
+    return load_module("archs", name)
+
+
 def load_cell(name: str):
+    """(BENCHMARK.json, the cell, its configuration, its traffic mix, its
+    configuration's architecture module)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {c["name"]: c for c in bench["workloads"]}
@@ -69,16 +89,7 @@ def load_cell(name: str):
     with open(os.path.join(ROOT, "bench", "traffic",
                            f"{cell['traffic']}.json")) as f:
         mix = json.load(f)
-    return bench, cell, conf, mix
-
-
-def rehearsal_widths() -> Dict:
-    """The program's tiny CI shapes, for a run on the CPU."""
-    from repro.configs.rtnerf import demo_config
-    tiny = dataclasses.asdict(demo_config(tiny=True))
-    keys = ("grid_res", "occ_res", "cube_size", "max_cubes", "r_sigma",
-            "r_color", "app_dim", "mlp_hidden")
-    return {k: tiny[k] for k in keys}
+    return bench, cell, conf, mix, load_arch(conf["arch"])
 
 
 def enable_compile_cache():
@@ -199,12 +210,13 @@ def warm_up(engine, mix: Dict, centers: np.ndarray, clog: CompileLog,
 
 
 def load_reader(name: str):
-    path = os.path.join(ROOT, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name).read
+
+
+def read_counters(engine) -> Dict[str, float]:
+    """Every counter of the program's registry, by flat name."""
+    snap = engine.store.metrics.snapshot()["counters"]
+    return {k: v["value"] for k, v in snap.items()}
 
 
 def cell_metrics(bench: Dict, cell: Dict, kind: str) -> List[Dict]:
@@ -225,32 +237,41 @@ class Window:
     t0: float                # first submit
     t_end: float             # the last view's result
     compiles: int            # backend compiles between the two
+    counters: Dict[str, float]   # the program's counters' change over it
 
 
 def serve_window(engine, mix: Dict, centers: np.ndarray, seed: int,
                  seconds: float, trace_dir: Optional[str],
                  clog: CompileLog) -> Window:
-    """The closed loop: views from the seed's stream for `seconds`, a view
-    submitted only while the last one's latency would end it inside them
-    (the first always), every one waited for and counted; or one whole
-    view under the profiler when `trace_dir` is given."""
+    """The closed loop: the seed's passes (`traffic.passes`) for
+    `seconds`, a pass started only while the last one's length would end
+    it inside them (the first always), every view of it served, waited
+    for and counted; or the first view alone under the profiler when
+    `trace_dir` is given."""
     import jax
     views: List[View] = []
+    last_pass_s = 0.0
+    before = read_counters(engine)
     t0 = time.perf_counter()
     if trace_dir:
         jax.profiler.start_trace(trace_dir)
-    for pose in traffic.poses(mix, centers, seed):
+    for batch in traffic.passes(mix, centers, seed):
         if views and (trace_dir or time.perf_counter()
-                      + views[-1].latency_s > t0 + seconds):
+                      + last_pass_s > t0 + seconds):
             break
-        views.append(serve(engine, pose))
+        served = [serve(engine, pose)
+                  for pose in (batch[:1] if trace_dir else batch)]
+        last_pass_s = sum(v.latency_s for v in served)
+        views += served
     if trace_dir:
         jax.profiler.stop_trace()
     t_end = views[-1].t_done
-    return Window(views, t0, t_end, clog.between(t0, t_end))
+    after = read_counters(engine)
+    return Window(views, t0, t_end, clog.between(t0, t_end),
+                  {k: v - before.get(k, 0.0) for k, v in after.items()})
 
 
-def check(views: List[View], params, w: Dict, centers: np.ndarray,
+def check(views: List[View], arch, params, w: Dict, centers: np.ndarray,
           chunk: int, seed: int, limits: Dict[str, float]):
     """(correct, [(number, reading, limit)], hits by view index): a sample
     of the served views drawn from the seed, the slowest among them,
@@ -265,7 +286,8 @@ def check(views: List[View], params, w: Dict, centers: np.ndarray,
     for i in sorted(pick):
         ro, rd = views[i].pose.rays()
         hits_of[i] = geometry.hits(w, centers, ro, rd, chunk)
-        refs.append(reference.render(params, w, hits_of[i], ro, rd))
+        refs.append(reference.render(arch.reference_field, params, w,
+                                     hits_of[i], ro, rd))
         imgs.append(views[i].img)
     correct, rows = compare.judge(compare.readings(imgs, refs), limits)
     return correct and not any(v.timed_out for v in views), rows, hits_of
@@ -282,19 +304,19 @@ def end_to_end(win: Window, s_bytes: int, setup_s: float) -> Dict:
     }
 
 
-def per_layer(bench, cell, win: Window, summary, peak, n_chips: int,
+def per_layer(bench, cell, arch, win: Window, summary, peak, n_chips: int,
               w: Dict, hits) -> Dict:
     """The cell's per-layer metrics from its readers; a reader that finds
     nothing to read leaves its metric out."""
-    ops = shapes.ops_per_sample(w)
+    ops = arch.ops_per_sample(w)
     ctx = {
         "views": win.views, "compiles_in_window": win.compiles,
         "window_s": win.t_end - win.t0, "trace": summary, "peak": peak,
-        "chips": n_chips, "widths": w,
+        "chips": n_chips, "widths": w, "counters": win.counters,
         "required_samples": shapes.required_samples(w, hits),
         "ops_per_sample": sum(ops.values()),
         "field_ops_per_sample": sum(ops.values()) - ops["composite"],
-        "bytes_per_view": shapes.bytes_per_view(w, win.views[0].pose.n_rays),
+        "bytes_per_view": arch.bytes_per_view(w, win.views[0].pose.n_rays),
     }
     out = {}
     for m in cell_metrics(bench, cell, "per_layer"):
@@ -314,10 +336,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     def log(msg):
         print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
-    bench, cell, conf, mix = load_cell(workload)
+    bench, cell, conf, mix, arch = load_cell(workload)
     w = dict(conf["field"])
     if rehearse:
-        w.update(rehearsal_widths())
+        w.update(arch.rehearsal_widths())
         conf = dict(conf, field=w)
 
     import jax
@@ -336,19 +358,16 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     limits = compare.load_limits(ROOT, workload)
     clog = CompileLog()
 
-    from repro.configs.rtnerf import NeRFConfig
-    from repro.core import field as field_lib
     from repro.core import occupancy as occ_lib
 
-    cfg = NeRFConfig(**w)
-    params = inputs.make_weights(conf, seed)
+    params = arch.make_weights(conf, seed)
     jax.block_until_ready(params)
+    cfg, field = arch.build(conf, w, params)
     occ = inputs.occupancy(conf)
     centers = inputs.cube_centers(conf, occ)
     cubes = occ_lib.extract_cubes(jnp.asarray(occ), cfg)
     scene = conf["scene"]["name"]
-    engine = make_engine(cfg, field_lib.DenseField(params, cfg), cubes, conf,
-                         scene, make_mesh(devs))
+    engine = make_engine(cfg, field, cubes, conf, scene, make_mesh(devs))
     log(f"scene {scene}: {len(centers)} cubes; field "
         f"{engine.stats()['field_kind']}, dispatch "
         f"{engine.field.dispatch_path()}; built at "
@@ -370,12 +389,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     mem = device.memory_peak_bytes(devs)
     chunk = engine.cube_chunk
     engine.close()                   # the reference runs with it freed
-    del engine
+    del engine, field
     gc.collect()
 
     t_r0 = time.perf_counter()
-    correct, rows, hits_of = check(win.views, params, w, centers, chunk,
-                                   seed, limits)
+    correct, rows, hits_of = check(win.views, arch, params, w, centers,
+                                   chunk, seed, limits)
     log(f"reference: {len(hits_of)} views in "
         f"{time.perf_counter() - t_r0:.3f}s")
 
@@ -390,9 +409,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             for m in cell_metrics(bench, cell, "end_to_end")}
     else:
         from bench import trace as trace_lib
-        summary = trace_lib.reduce_dir(trace_dir)
+        summary = trace_lib.reduce_dir(trace_dir, arch.SCOPES)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        result["metrics"] = per_layer(bench, cell, win, summary, peak,
+        result["metrics"] = per_layer(bench, cell, arch, win, summary, peak,
                                       len(devs), w, hits_of[0])
         if summary is not None:
             result["device"]["busy_s"] = summary.busy_s

@@ -1,4 +1,6 @@
-"""Inputs made from the seed: the field's weights and the scene's cubes.
+"""Inputs made from the seed and the configuration: the streams of a
+run's seed and the scene's cubes (the field's weights come from the
+configuration's architecture, `bench/archs/<arch>.py`).
 
 Both sides of the comparison read what this module makes: the program
 under test (through `RenderEngine`) and `bench/reference.py`. Nothing here
@@ -9,73 +11,12 @@ from __future__ import annotations
 
 import numpy as np
 
-FACTOR_KEYS = ("sigma_planes", "sigma_lines", "app_planes", "app_lines")
-
 
 def seed_words(seed: int, stream: int) -> int:
     """A 32-bit key for one named stream of a run's seed. `seed` may be any
     non-negative integer, larger than 32 bits included."""
     return int(np.random.SeedSequence([int(seed), int(stream)])
                .generate_state(1)[0])
-
-
-def shapes(w: dict) -> dict:
-    """Shape of every leaf of the TensoRF VM parameter set, from the
-    configuration's widths (`w` is the configuration's "field" object)."""
-    g, rs, rc = w["grid_res"], w["r_sigma"], w["r_color"]
-    d_in = 3 + 6 * w["pe_view"] + w["app_dim"] * (1 + 2 * w["pe_feat"])
-    h = w["mlp_hidden"]
-    return {
-        "sigma_planes": (3, rs, g, g), "sigma_lines": (3, rs, g),
-        "app_planes": (3, rc, g, g), "app_lines": (3, rc, g),
-        "basis": (3 * rc, w["app_dim"]),
-        "mlp_w1": (d_in, h), "mlp_b1": (h,),
-        "mlp_w2": (h, h), "mlp_b2": (h,),
-        "mlp_w3": (h, 3), "mlp_b3": (3,),
-    }
-
-
-def make_weights(conf: dict, seed: int):
-    """The pruned float32 parameter set, made on the device in one jitted
-    call. Factors are N(0, s^2) with the configuration's `init_scale` per
-    key; each mode slice of a factor key keeps exactly its largest
-    (1 - sparsity) share of entries by magnitude (the rule of
-    `tensorf.prune_to_sparsity`, applied per slice so that every seed gives
-    the same number of non-zeros and so the same encoded shapes). Matrices
-    are fan-in scaled normals, biases zero."""
-    import jax
-    import jax.numpy as jnp
-
-    shp = shapes(conf["field"])
-    scale = conf["init_scale"]
-    keep = {k: 1.0 - conf["sparsity"][k] for k in FACTOR_KEYS}
-
-    def prune(w, frac):
-        flat = w.reshape(w.shape[0], -1)                 # one row per mode
-        k = int(round(frac * flat.shape[1]))
-        # exactly k kept per row, by rank: equal magnitudes at the cut
-        # (+x and -x) must not change the count, and with it the shapes
-        keep_idx = jnp.argsort(-jnp.abs(flat), axis=1)[:, :k]
-        rows = jnp.arange(flat.shape[0])[:, None]
-        kept = jnp.zeros(flat.shape, bool).at[rows, keep_idx].set(True)
-        return jnp.where(kept, flat, 0.0).reshape(w.shape)
-
-    def build(key):
-        keys = dict(zip(sorted(shp), jax.random.split(key, len(shp))))
-        out = {}
-        for name, s in shp.items():
-            if name in FACTOR_KEYS:
-                w = jax.random.normal(keys[name], s, jnp.float32) * scale[name]
-                out[name] = prune(w, keep[name])
-            elif name.startswith("mlp_b"):
-                out[name] = jnp.zeros(s, jnp.float32)
-            else:
-                out[name] = (jax.random.normal(keys[name], s, jnp.float32)
-                             / np.sqrt(s[0]))
-        return out
-
-    key = jax.random.PRNGKey(seed_words(seed, 0))
-    return jax.jit(build)(key)
 
 
 def scene_sdf(prims, pts: np.ndarray) -> np.ndarray:

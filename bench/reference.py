@@ -1,20 +1,18 @@
-"""Plain reference of the served view: a TensoRF VM field (Chen et al.,
-ECCV 2022, Eq. 2 of RT-NeRF) rendered through RT-NeRF's per-cube segments.
+"""Plain reference of the served view: the configuration's field rendered
+through RT-NeRF's per-cube segments.
 
 Straightforward float32 `jax.numpy`, every matmul at full float32
-(`Precision.HIGHEST`), with no compaction, pair budget, micro-batching or sparse
-encoding: every hitting (ray, cube) pair of `bench/geometry.py` is
-evaluated, in blocks of pairs so that it fits, from the dense pruned
-factors that `bench/inputs.py` made. It imports nothing of the program.
+(`Precision.HIGHEST`), with no compaction, pair budget, micro-batching or
+sparse encoding: every hitting (ray, cube) pair of `bench/geometry.py` is
+evaluated, in blocks of pairs so that it fits. The field itself is the
+architecture's reference field (`bench/archs/<arch>.py`, with its own
+departures), over the weights the architecture made from the seed. This
+module holds what every architecture shares, and imports nothing of the
+program.
 
-Departures from TensoRF, each one the served renderer's and written here
-so that the reference renders what the renderer is specified to render:
+Departures, each one the served renderer's and written here so that the
+reference renders what the renderer is specified to render:
 
-* density is softplus of the VM sum with no shift (TensoRF shifts by -10);
-* grid coordinates map [-bound, bound] onto [0, G-1] (corner-aligned),
-  bilinear on planes, linear on lines, clipped at the border;
-* the colour MLP reads (dir, PE(dir), feat, PE(feat)) with sin/cos bands
-  2^i, ReLU, ReLU, sigmoid (TensoRF's MLPRender_Fea order);
 * samples lie at t0 + (k + 1/2) * step inside each cube's slab segment,
   t0 clipped to `near`; a segment composites front to back within itself;
 * cubes are composited `cube_chunk` at a time in the view's octant order
@@ -35,65 +33,17 @@ import numpy as np
 
 from bench import geometry
 
-PLANE_AXES = ((1, 2), (0, 2), (0, 1))   # mode m: plane over these axes,
-LINE_AXES = (0, 1, 2)                   # line along this one
-
-
-def _bands(x, n_bands):
-    out = [x]
-    for i in range(n_bands):
-        out += [jnp.sin((2.0 ** i) * x), jnp.cos((2.0 ** i) * x)]
-    return jnp.concatenate(out, axis=-1)
-
-
-def _vm(planes, lines, g):
-    """Per-point VM components (N, 3*R) from grid coords g (N, 3)."""
-    G = planes.shape[-1]
-    g = jnp.clip(g, 0.0, G - 1.0)
-    i0 = jnp.clip(jnp.floor(g).astype(jnp.int32), 0, G - 2)
-    f = g - i0
-    comps = []
-    for m in range(3):
-        a, b = PLANE_AXES[m]
-        c = LINE_AXES[m]
-        P, L = planes[m], lines[m]                     # (R,G,G), (R,G)
-        ua, ub, uc = i0[:, a], i0[:, b], i0[:, c]
-        fa, fb, fc = f[:, a, None], f[:, b, None], f[:, c, None]
-        pv = (P[:, ua, ub].T * (1 - fa) * (1 - fb)
-              + P[:, ua, ub + 1].T * (1 - fa) * fb
-              + P[:, ua + 1, ub].T * fa * (1 - fb)
-              + P[:, ua + 1, ub + 1].T * fa * fb)
-        lv = L[:, uc].T * (1 - fc) + L[:, uc + 1].T * fc
-        comps.append(pv * lv)
-    return jnp.concatenate(comps, axis=-1)
-
-
 def matmul(x, y):
     """Full float32 matmul on every backend (`Precision.HIGHEST`)."""
     return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST)
 
 
-def field_rgb_sigma(params, w: dict, pts, dirs):
-    """(sigma (N,), rgb (N, 3)) of the field at world points."""
-    g = (pts / w["scene_bound"] * 0.5 + 0.5) * (w["grid_res"] - 1)
-    sigma = jax.nn.softplus(jnp.sum(
-        _vm(params["sigma_planes"], params["sigma_lines"], g), axis=-1))
-    feat = matmul(_vm(params["app_planes"], params["app_lines"], g),
-                  params["basis"])
-    x = jnp.concatenate([_bands(dirs, w["pe_view"]),
-                         _bands(feat, w["pe_feat"])], axis=-1)
-    h = jax.nn.relu(matmul(x, params["mlp_w1"]) + params["mlp_b1"])
-    h = jax.nn.relu(matmul(h, params["mlp_w2"]) + params["mlp_b2"])
-    rgb = jax.nn.sigmoid(matmul(h, params["mlp_w3"]) + params["mlp_b3"])
-    return sigma, rgb
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _segments(params, w, pts, dirs, mask, delta):
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _segments(field, params, w, pts, dirs, mask, delta):
     """Per-pair colour and optical depth of each segment (P,3), (P,)."""
     p, ns = mask.shape
-    sigma, rgb = field_rgb_sigma(params, w, pts.reshape(-1, 3),
-                                 jnp.repeat(dirs, ns, axis=0))
+    sigma, rgb = field(params, w, pts.reshape(-1, 3),
+                       jnp.repeat(dirs, ns, axis=0))
     sigma = jnp.where(mask, sigma.reshape(p, ns), 0.0)
     tau = sigma * delta
     cum = jnp.cumsum(tau, axis=-1)
@@ -102,28 +52,32 @@ def _segments(params, w, pts, dirs, mask, delta):
     return seg_rgb, cum[:, -1]
 
 
-def render(params, w: dict, h: geometry.Hits, rays_o: np.ndarray,
+def render(field, params, w: dict, h: geometry.Hits, rays_o: np.ndarray,
            rays_d: np.ndarray, *, dtype: str = "float32",
            block: int = 8192):
     """(image (N, 3) float32, {ray: (m, 3) colours it may take}) of one
-    view from its hits (`geometry.hits`). A sample within a few float32
-    ulps of its segment's end lies on either side of it on a chip whose
-    division or fused multiply-add rounds otherwise than numpy's; every
-    ray that holds such samples gets the colour of each way they may fall.
+    view from its hits (`geometry.hits`), with `field(params, w, pts,
+    dirs) -> (sigma, rgb)` the architecture's reference field. A sample
+    within a few float32 ulps of its segment's end lies on either side of
+    it on a chip whose division or fused multiply-add rounds otherwise than
+    numpy's; every ray that holds such samples gets the colour of each way
+    they may fall.
     A lower `dtype` gives a control that must come out not correct."""
     dt = jnp.dtype(dtype)
     ts, mask = geometry.sample_ts(w, h)
     flip = np.abs(ts - h.t1[:, None]) <= np.float32(1e-6) * h.t1[:, None]
     prm = {k: jnp.asarray(v, dt) for k, v in params.items()}
-    seg = _segment_table(prm, w, h, ts, mask, rays_o, rays_d, dt, block)
+    seg = _segment_table(field, prm, w, h, ts, mask, rays_o, rays_d, dt,
+                         block)
     img = _composite(w, h, *seg, len(rays_o))
     amb = np.nonzero(flip.any(axis=1))[0]
     if not len(amb):
         return img, {}
     sub = geometry.Hits(h.step[amb], h.ray[amb], h.t0[amb], h.t1[amb],
                         h.n_steps)
-    flipped = _segment_table(prm, w, sub, ts[amb], mask[amb] ^ flip[amb],
-                             rays_o, rays_d, dt, block)
+    flipped = _segment_table(field, prm, w, sub, ts[amb],
+                             mask[amb] ^ flip[amb], rays_o, rays_d, dt,
+                             block)
     alt_of = {int(p): (flipped[0][i], flipped[1][i])
               for i, p in enumerate(amb)}
     alts = {}
@@ -145,7 +99,8 @@ def render(params, w: dict, h: geometry.Hits, rays_o: np.ndarray,
 MAX_AMBIGUOUS = 6            # samples per ray tried both ways, at most
 
 
-def _segment_table(prm, w, h, ts, mask, rays_o, rays_d, dt, block):
+def _segment_table(field, prm, w, h, ts, mask, rays_o, rays_d, dt,
+                   block):
     """(P, 3) colour and (P,) optical depth of every pair's segment."""
     frozen = _Frozen(tuple(sorted(w.items())))
     delta = jnp.asarray(geometry.step_world(w), dt)
@@ -159,7 +114,7 @@ def _segment_table(prm, w, h, ts, mask, rays_o, rays_d, dt, block):
         pad = block - (e - s)            # one block shape: one compile
         args = [np.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
                 for a in (pts, rays_d[ri], mask[s:e])]
-        r, t = _segments(prm, frozen, jnp.asarray(args[0], dt),
+        r, t = _segments(field, prm, frozen, jnp.asarray(args[0], dt),
                          jnp.asarray(args[1], dt), jnp.asarray(args[2]),
                          delta)
         seg_rgb[s:e] = np.asarray(r.astype(jnp.float32))[:e - s]

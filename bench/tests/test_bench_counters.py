@@ -3,7 +3,7 @@ of required ones, on the CPU at tiny widths."""
 import jax.numpy as jnp
 import numpy as np
 
-from bench.tests.test_bench_shapes import widths
+from bench.tests.test_bench_shapes import arch, widths
 
 
 def test_engine_samples_processed_equal_required_samples():
@@ -11,19 +11,17 @@ def test_engine_samples_processed_equal_required_samples():
     engine's `engine_samples_processed` for a view is exactly the
     required samples of that view."""
     from bench import geometry, harness, inputs, shapes, traffic
-    from repro.configs.rtnerf import NeRFConfig
-    from repro.core import field as field_lib
     from repro.core import occupancy as occ_lib
     from repro.core import rendering
 
     conf = widths()
     conf["field"]["term_eps"] = 0.0
     w = conf["field"]
-    cfg = NeRFConfig(**w)
+    vm = arch()
+    cfg, field = vm.build(conf, w, vm.make_weights(conf, 3))
     occ = inputs.occupancy(conf)
     centers = inputs.cube_centers(conf, occ)
     cubes = occ_lib.extract_cubes(jnp.asarray(occ), cfg)
-    field = field_lib.DenseField(inputs.make_weights(conf, 3), cfg)
     mix = {"view": {"h": 16, "w": 16, "focal": 19.2},
            "origin": {"radius": 4.0, "azimuth": [0.0, 6.3],
                       "elevation": [0.2, 0.8]}, "look_at": "occupied_cube"}

@@ -1,5 +1,5 @@
-"""The closed loop's two rules, on a scripted engine and clock: when the
-window stops submitting, and when warm-up stops."""
+"""The closed loop's rules, on a scripted engine and clock: when the
+window stops submitting, what a pass holds, and when warm-up stops."""
 import numpy as np
 import pytest
 
@@ -41,7 +41,9 @@ def test_window_counts_every_view_it_submits(monkeypatch, clock, latency,
         return harness.View(pose, clock.t, None, latency, latency, False)
 
     monkeypatch.setattr(harness, "serve", serve)
-    _, _, _, mix = harness.load_cell(cells()[0])
+    monkeypatch.setattr(harness, "read_counters", lambda engine: {})
+    _, _, _, mix, _ = harness.load_cell(cells()[0])
+    mix = dict(mix, pool={"seed": 7, "views": 1})        # passes of one
     win = harness.serve_window(None, mix, CENTERS, 2**31 + 3, seconds, None,
                                NoCompiles())
     assert len(win.views) == n_views
@@ -50,6 +52,44 @@ def test_window_counts_every_view_it_submits(monkeypatch, clock, latency,
     assert e2e["rays_per_s"] == pytest.approx(
         n_views * win.views[0].pose.n_rays / (n_views * latency))
     assert e2e["view_ms_p95"] == pytest.approx(latency * 1e3)
+
+
+@pytest.mark.parametrize("latency,seconds,n_passes", [
+    (1.0, 10.0, 3),       # passes of 3 s: 0-3, 3-6, 6-9; a fourth ends at 12
+    (4.0, 10.0, 1),       # the first pass is served whole, past the close
+])
+def test_window_serves_whole_passes_of_the_pool(monkeypatch, clock, latency,
+                                                seconds, n_passes):
+    def serve(engine, pose):
+        clock.t += latency
+        return harness.View(pose, clock.t, None, latency, latency, False)
+
+    monkeypatch.setattr(harness, "serve", serve)
+    monkeypatch.setattr(harness, "read_counters", lambda engine: {})
+    _, _, _, mix, _ = harness.load_cell(cells()[0])
+    mix = dict(mix, pool={"seed": 7, "views": 3})
+    win = harness.serve_window(None, mix, CENTERS, 2**31 + 3, seconds, None,
+                               NoCompiles())
+    assert len(win.views) == 3 * n_passes
+    assert win.t_end == pytest.approx(3 * n_passes * latency)
+
+
+def test_every_seed_gets_the_pool_in_its_own_order():
+    from bench import traffic
+    _, _, _, mix, _ = harness.load_cell(cells()[0])
+    mix = dict(mix, pool={"seed": 7, "views": 5})
+
+    def key(p):
+        return tuple(p.origin.tolist())
+    orders = []
+    for seed in (2**31 + 3, 11):
+        it = traffic.passes(mix, CENTERS, seed)
+        first, second = next(it), next(it)
+        assert [key(p) for p in first] == [key(p) for p in second]
+        orders.append([key(p) for p in first])
+    assert sorted(orders[0]) == sorted(orders[1])
+    assert orders[0] != orders[1]
+    assert len(set(orders[0])) == 5
 
 
 class ScriptedEngine:
@@ -81,6 +121,6 @@ def test_warm_up_stops_once_the_budget_settles(monkeypatch, clock, script,
         return harness.View(pose, clock.t, None, 1.0, 1.0, False)
 
     monkeypatch.setattr(harness, "serve", serve)
-    _, _, _, mix = harness.load_cell(cells()[0])
+    _, _, _, mix, _ = harness.load_cell(cells()[0])
     n = harness.warm_up(engine, mix, CENTERS, NoCompiles(), lambda m: None)
     assert n == n_views

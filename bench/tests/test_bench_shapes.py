@@ -11,13 +11,17 @@ import pytest
 from bench.tests.common import ROOT
 
 
-def widths(tiny=True):
+def arch():
     from bench import harness
+    return harness.load_arch("tensorf_vm")
+
+
+def widths(tiny=True):
     with open(os.path.join(ROOT, "bench", "configs",
                            "tensorf-vm-hybrid.json")) as f:
         conf = json.load(f)
     if tiny:
-        conf["field"].update(harness.rehearsal_widths())
+        conf["field"].update(arch().rehearsal_widths())
     return conf
 
 
@@ -26,19 +30,17 @@ def test_required_samples_equal_processed_samples(look_at):
     """With no early termination and a budget that drops nothing, the
     renderer processes exactly the required samples."""
     from bench import geometry, inputs, shapes, traffic
-    from repro.configs.rtnerf import NeRFConfig
-    from repro.core import field as field_lib
     from repro.core import occupancy as occ_lib
     from repro.core import pipeline
 
     conf = widths()
     conf["field"]["term_eps"] = 0.0
     w = conf["field"]
-    cfg = NeRFConfig(**w)
+    vm = arch()
+    cfg, field = vm.build(conf, w, vm.make_weights(conf, 3))
     occ = inputs.occupancy(conf)
     centers = inputs.cube_centers(conf, occ)
     cubes = occ_lib.extract_cubes(jnp.asarray(occ), cfg)
-    field = field_lib.DenseField(inputs.make_weights(conf, 3), cfg)
     mix = {"view": {"h": 16, "w": 16, "focal": 19.2},
            "origin": {"radius": 4.0, "azimuth": [0.0, 6.3],
                       "elevation": [0.2, 0.8]}, "look_at": look_at}
@@ -71,10 +73,10 @@ def test_ops_per_sample_against_xla(tiny):
     """The MLP and the basis projection, most of the work, match XLA's
     count exactly; the whole field evaluation within what the reference's
     index arithmetic (clips, floors, repeated weights) adds."""
-    from bench import inputs, reference, shapes
+    vm = arch()
     w = widths(tiny)["field"]
-    ops = shapes.ops_per_sample(w)
-    shp = inputs.shapes(w)
+    ops = vm.ops_per_sample(w)
+    shp = vm.shapes(w)
     n = 512
     p = {k: v for k, v in shp.items()}
     d_in = shp["mlp_w1"][0]
@@ -91,17 +93,16 @@ def test_ops_per_sample_against_xla(tiny):
 
     def field(*a):
         prm = dict(zip(sorted(shp), a[:-2]))
-        return reference.field_rgb_sigma(prm, w, a[-2], a[-1])
+        return vm.reference_field(prm, w, a[-2], a[-1])
     total = xla_ops(field, *(p[k] for k in sorted(shp)), (n, 3), (n, 3))
     mine = sum(ops.values()) - ops["composite"]
     assert mine <= total <= mine * (1.2 if tiny else 1.03)
 
 
 def test_bytes_per_view():
-    from bench import shapes
     w = widths(tiny=False)["field"]
     mlp = 150 * 128 + 128 * 128 + 128 * 3 + 128 + 128 + 3
-    assert shapes.bytes_per_view(w, 4096) == 4 * (9 * 4096 + mlp)
+    assert arch().bytes_per_view(w, 4096) == 4 * (9 * 4096 + mlp)
 
 
 def test_scene_arrays_found_once():

@@ -7,6 +7,10 @@ import pytest
 
 from bench import trace
 from bench.tests.common import ROOT
+from bench.tests.test_bench_shapes import arch
+
+FIELD_SCOPES = arch().SCOPES
+NAMED = trace.SCOPES + FIELD_SCOPES
 
 
 def op(name, scope, s, e):
@@ -21,10 +25,14 @@ def test_merge_and_clip():
 
 def test_scope_of_takes_the_innermost():
     assert trace.scope_of("jit(render)/while/body/rtnerf.field_eval/"
-                          "fused.decode.m0/gather") == "fused.decode"
-    assert trace.scope_of("jit(render)/rtnerf.compact/sort") == \
+                          "fused.decode.m0/gather", NAMED) == "fused.decode"
+    assert trace.scope_of("jit(render)/rtnerf.compact/sort", NAMED) == \
         "rtnerf.compact"
-    assert trace.scope_of("jit(camera_rays)/mul") is None
+    assert trace.scope_of("jit(camera_rays)/mul", NAMED) is None
+    # without the architecture's scopes, its ops count in field_eval only
+    assert trace.scope_of("jit(render)/while/body/rtnerf.field_eval/"
+                          "fused.decode.m0/gather",
+                          trace.SCOPES) == "rtnerf.field_eval"
 
 
 def test_reduce_hand_made():
@@ -36,7 +44,7 @@ def test_reduce_hand_made():
             op("fusion.2", "fused.decode", 5.5, 6.0),
             op("sort.1", "rtnerf.compact", 9.8, 11.0)]    # cut at 10
     dev1 = [op("fusion.1", "rtnerf.intersect", 0.0, 4.0)]
-    s = trace.reduce([dev0, dev1], host)
+    s = trace.reduce([dev0, dev1], host, FIELD_SCOPES)
     assert s.window_s == pytest.approx(10.0)
     # device 0 busy 1 + 2 + 0.5 + 0.2, device 1 busy 4: mean 3.85
     assert s.busy_s == pytest.approx(3.85)
@@ -59,15 +67,17 @@ def test_a_control_op_counts_as_busy_not_as_an_op():
     dev = [op("while.7", None, 1.0, 9.0),
            op("fusion.3", "rtnerf.field_eval", 2.0, 4.0),
            op("fusion.4", "rtnerf.scatter", 4.0, 5.0)]
-    s = trace.reduce([dev], host)
+    s = trace.reduce([dev], host, ())
     assert s.busy_s == pytest.approx(8.0)
     assert [n for n, _ in s.top_ops] == ["rtnerf.field_eval:fusion.3",
                                          "rtnerf.scatter:fusion.4"]
 
 
 def test_reduce_needs_device_ops_and_annotations():
-    assert trace.reduce([], [("bench.submit", 0, 1)]) is None
-    assert trace.reduce([[op("f", None, 0, 1)]], [("other", 0, 1)]) is None
+    assert trace.reduce([], [("bench.submit", 0, 1)],
+                        FIELD_SCOPES) is None
+    assert trace.reduce([[op("f", None, 0, 1)]], [("other", 0, 1)],
+                        FIELD_SCOPES) is None
 
 
 @pytest.fixture(scope="module")
@@ -89,7 +99,7 @@ def test_recorded_trace(tiny_trace):
     a 1-us timeline of the raw events (0.535927 s, 0.494853 s); the
     scopes of fusion.493 (fused.sample.m2) and fusion.446 (field_eval,
     outside fused.*) read from the op paths in the file's bytes."""
-    s = trace.reduce(*trace.read(tiny_trace))
+    s = trace.reduce(*trace.read(tiny_trace, FIELD_SCOPES), FIELD_SCOPES)
     assert s.n_devices == 1
     assert s.window_s == pytest.approx(0.535927065, abs=1e-9)
     assert s.busy_s == pytest.approx(0.494853, abs=5e-6)
@@ -105,3 +115,19 @@ def test_recorded_trace(tiny_trace):
     # every gap is named by a host event of the thread that drove the run
     assert s.idle_gaps and all(n != "-" for n, _ in s.idle_gaps[:10])
     assert s.breakdown()["device_ops"][0][1] > 0
+
+
+def test_recorded_trace_reduces_as_before(tiny_trace):
+    """Every number of the reduction (window, busy time, time per scope,
+    every op and every idle gap) is the one the reduction gave before the
+    architecture named its own scopes: the SHA-256 of the summary as JSON,
+    taken with the `fused.*` scopes written into `bench/trace.py`."""
+    import hashlib
+    import json
+    s = trace.reduce(*trace.read(tiny_trace, FIELD_SCOPES), FIELD_SCOPES)
+    blob = json.dumps({"window_s": s.window_s, "busy_s": s.busy_s,
+                       "scope_s": sorted(s.scope_s.items()),
+                       "top_ops": s.top_ops, "idle_gaps": s.idle_gaps})
+    assert (len(s.top_ops), len(s.idle_gaps)) == (732, 137)
+    assert hashlib.sha256(blob.encode()).hexdigest() == \
+        "34d76061fb22a4c9421cf04969622497a04686673426f872123d998b49fda26a"

@@ -9,7 +9,10 @@ traced under; and, on the host, the thread that holds the harness's
   one's end;
 * busy time: the union of the intervals in which an op ran on a device,
   inside the window, averaged over the devices;
-* device time per named scope (`rtnerf.*`, `fused.*`), summed over ops;
+* device time per named scope, summed over ops: the renderer's
+  `rtnerf.*` and the field-evaluation scopes of the cell's architecture
+  (`SCOPES` of `bench/archs/<arch>.py`), which also count in
+  `rtnerf.field_eval`;
 * the longest device ops, by scope, and the longest idle gaps, each named
   by the innermost host event that covers its middle.
 """
@@ -18,13 +21,13 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 OP_LINES = ("XLA Ops",)
 HOST_MARK = "bench."
 SCOPES = ("rtnerf.intersect", "rtnerf.compact", "rtnerf.field_eval",
-          "rtnerf.composite", "rtnerf.scatter", "fused.decode",
-          "fused.sample", "fused.accumulate")
+          "rtnerf.composite", "rtnerf.scatter")
+FIELD_EVAL = "rtnerf.field_eval"
 
 
 def merge(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -44,10 +47,10 @@ def clip(intervals, lo: float, hi: float):
             if e > lo and s < hi]
 
 
-def scope_of(text: str) -> Optional[str]:
-    """The innermost of SCOPES named in an op's scope path, if any."""
+def scope_of(text: str, scopes: Sequence[str]) -> Optional[str]:
+    """The innermost of `scopes` named in an op's scope path, if any."""
     best, at = None, -1
-    for sc in SCOPES:
+    for sc in scopes:
         i = text.rfind(sc)
         if i > at:
             best, at = sc, i
@@ -114,7 +117,8 @@ def _fields(buf: bytes):
             raise ValueError(f"wire type {wt} in an XSpace")
 
 
-def op_scopes(path: str) -> Dict[str, Optional[str]]:
+def op_scopes(path: str, scopes: Sequence[str]
+              ) -> Dict[str, Optional[str]]:
     """HLO op text -> the named scope its op path names, from the device
     planes' event metadata (XSpace: planes = 1; XPlane: name = 2,
     event_metadata = 4; XEventMetadata: name = 2, stats = 5; XStat:
@@ -147,16 +151,18 @@ def op_scopes(path: str) -> Dict[str, Optional[str]]:
                                   for f5, wt, x in _fields(v)
                                   if f5 == 5 and wt == 2]
                 if op is not None:
-                    out[op] = scope_of(" ".join(texts))
+                    out[op] = scope_of(" ".join(texts), scopes)
     return out
 
 
-def read(path: str):
+def read(path: str, field_scopes: Sequence[str]):
     """(device ops per device, host events (name, start, end)) of one
-    `.xplane.pb`, times in seconds on the trace's clock."""
+    `.xplane.pb`, times in seconds on the trace's clock; each op carries
+    the innermost of the renderer's and `field_scopes` it ran under."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    scopes = op_scopes(path)
+    named = SCOPES + tuple(field_scopes)
+    scopes = op_scopes(path, named)
     devices: List[List[Op]] = []
     host: List[Tuple[str, float, float]] = []
     for plane in pd.planes:
@@ -167,7 +173,7 @@ def read(path: str):
                     continue
                 for ev in line.events:
                     s = ev.start_ns * 1e-9
-                    sc = scopes.get(ev.name) or scope_of(ev.name)
+                    sc = scopes.get(ev.name) or scope_of(ev.name, named)
                     ops.append(Op(_short(ev.name), sc, s,
                                   s + ev.duration_ns * 1e-9))
             if ops:
@@ -197,9 +203,11 @@ def leaves(ops: List[Op]) -> List[Op]:
     return out
 
 
-def reduce(devices: List[List[Op]], host) -> Optional[Summary]:
+def reduce(devices: List[List[Op]], host,
+           field_scopes: Sequence[str]) -> Optional[Summary]:
     """The benchmark's numbers from one trace; None without device ops or
-    without the harness's annotations."""
+    without the harness's annotations. Time in `field_scopes` counts in
+    `rtnerf.field_eval` too."""
     marks = [(s, e) for n, s, e in host if n.startswith(HOST_MARK)]
     if not devices or not marks:
         return None
@@ -213,9 +221,8 @@ def reduce(devices: List[List[Op]], host) -> Optional[Summary]:
                 continue
             if o.scope is not None:
                 scope_s[o.scope] = scope_s.get(o.scope, 0.0) + d
-                if o.scope.startswith("fused."):
-                    scope_s["rtnerf.field_eval"] = \
-                        scope_s.get("rtnerf.field_eval", 0.0) + d
+                if o.scope in field_scopes:
+                    scope_s[FIELD_EVAL] = scope_s.get(FIELD_EVAL, 0.0) + d
             key = f"{o.scope or '-'}:{o.name}"
             per_op[key] = per_op.get(key, 0.0) + d
     busy_s = sum(sum(e - s for s, e in b) for b in busy) / len(devices)
@@ -236,8 +243,9 @@ def reduce(devices: List[List[Op]], host) -> Optional[Summary]:
     return Summary(hi - lo, busy_s, len(devices), scope_s, top, gaps)
 
 
-def reduce_dir(d: str) -> Optional[Summary]:
+def reduce_dir(d: str, field_scopes: Sequence[str]
+               ) -> Optional[Summary]:
     paths = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
     if not paths:
         return None
-    return reduce(*read(sorted(paths)[-1]))
+    return reduce(*read(sorted(paths)[-1], field_scopes), field_scopes)

@@ -6,11 +6,19 @@ ranges in radians) and what each view looks at: `"origin"` (the scene
 centre) or `"occupied_cube"` (the centre of an occupied cube drawn
 uniformly: a gaze point on the geometry). `warmup_seed` fixes the views
 that warm the server up, so that every run's set-up does the same work.
+
+The window serves passes over the mix's `pool` ({"seed": s, "views":
+k}): the first k poses of seed s's stream, in one order drawn from the
+run's seed and repeated pass after pass. Every seed gives the window the
+same work, so that a run's rate does not depend on which poses its seed
+drew. Repeating one order makes a run's passes alike: the engine's
+adaptive pair budget, which shrinks after three views in a row fill
+under a quarter of it, shrinks in a seed's first pass or not at all.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator
+from typing import Iterator, List
 
 import numpy as np
 
@@ -57,6 +65,18 @@ def poses(mix: dict, centers: np.ndarray, seed: int,
             raise ValueError(f"unknown look_at {mix['look_at']!r}")
         yield Pose(origin, geometry.look_at(origin, target),
                    float(v["focal"]), int(v["h"]), int(v["w"]))
+
+
+def passes(mix: dict, centers: np.ndarray, seed: int
+           ) -> Iterator[List[Pose]]:
+    """Endless passes of the window of one mix for one seed."""
+    pool = mix["pool"]
+    fixed = [p for p, _ in zip(poses(mix, centers, pool["seed"]),
+                               range(pool["views"]))]
+    rng = np.random.default_rng(seed_words(seed, WINDOW_STREAM))
+    order = [fixed[i] for i in rng.permutation(len(fixed))]
+    while True:
+        yield order
 
 
 def warmup_poses(mix: dict, centers: np.ndarray) -> Iterator[Pose]:
